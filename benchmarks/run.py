@@ -30,6 +30,8 @@ SUITES = (
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full-size sweeps (quick by default)")

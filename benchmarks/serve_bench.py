@@ -248,6 +248,8 @@ def run(quick: bool = True):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small qwen3 smoke shapes (CI)")

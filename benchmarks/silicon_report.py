@@ -300,7 +300,7 @@ def _kernel_section(params, cfg, cim, rows, quick):
         served σ>0 token streams of the fused and einsum engines match.
     """
     from benchmarks.serve_bench import _decode_tok_per_s
-    from repro.kernels.ops import _on_cpu
+    from repro.kernels.ops import interpret_mode
     cim_k = dataclasses.replace(cim, use_kernel=True)
     cfg_k = _lm_cfg(cim_k)
     fleet = Fleet(n_macros=4096, cfg=cim_k)
@@ -322,7 +322,7 @@ def _kernel_section(params, cfg, cim, rows, quick):
                  f"nominal_fused={nom_tok_s:.1f}tok/s "
                  f"silicon_fused={sil_tok_s:.1f}tok/s ratio={ratio:.2f} "
                  f"gate>={1.0 / KERNEL_SLOWDOWN_GATE:.2f} "
-                 f"interpret={_on_cpu()}"))
+                 f"interpret={interpret_mode()}"))
 
     sigma0 = SiliconConfig(cap_sigma=0.0, comparator_sigma_v=0.0)
     sigma0_ok = (_greedy_tokens(mk(cfg_k, sigma0, ml=16), 4, 2)
@@ -345,7 +345,7 @@ def _kernel_section(params, cfg, cim, rows, quick):
         "cap_sigma": scfg.cap_sigma,
         "comparator_sigma_v": scfg.comparator_sigma_v,
         "decode_ticks": ticks * reps,
-        "pallas_interpret": bool(_on_cpu()),
+        "pallas_interpret": bool(interpret_mode()),
         "nominal_fused_tok_s": nom_tok_s,
         "silicon_fused_tok_s": sil_tok_s,
         "silicon_over_nominal_ratio": ratio,
@@ -478,6 +478,8 @@ def run_kernel(quick: bool = True):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small seed counts (CI)")

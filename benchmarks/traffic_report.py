@@ -14,10 +14,12 @@ to ``cim_sim`` and served from a pinned fleet:
   * **mesh parity** — a single-device serve mesh
     (:func:`repro.traffic.shard.shard_engine`) must decode bitwise
     identically to the unsharded engine. Gate: hard assert.
-  * **multi-device scaling** — a subprocess forces
-    ``--xla_force_host_platform_device_count`` host devices and measures
-    steady-state aggregate decode tok/s on a data-parallel serve mesh vs
-    the single-device engine. Gate: >= 1.5x, asserted ONLY when the host
+  * **multi-device scaling** — steady-state aggregate decode tok/s on a
+    data-parallel serve mesh vs the single-device engine, measured
+    in-process over the devices this process holds; a CPU-only process
+    (``JAX_PLATFORMS=cpu``) rehearses in a CPU child that forces
+    ``--xla_force_host_platform_device_count`` host devices. Gate (CPU
+    rehearsal only): >= 1.5x, asserted ONLY when the host
     actually has >= 2 cores (XLA's forced host devices share one thread
     pool per core; on a 1-core machine the gate is recorded as vacuous
     with ``host_parallel_capable: false``). CI exports
@@ -178,29 +180,14 @@ def _mesh_parity(params, cfg, fleet):
     return outs[0] == outs[1], info
 
 
-MULTIDEV_SCRIPT = textwrap.dedent("""
-    import os, json, time
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    import sys
-    sys.path.insert(0, "src")
-    import jax, jax.numpy as jnp, numpy as np
-    import dataclasses
-    from repro.compiler.tiling import Fleet
-    from repro.configs.base import MFTechniqueConfig
-    from repro.configs.qwen3_0_6b import SMOKE
-    from repro.core.cim import CimConfig
-    from repro.launch.mesh import make_serve_mesh
-    from repro.models import transformer as T
-    from repro.serve.engine import Request, ServeEngine
-    from repro.traffic import shard_engine
-
-    cim = CimConfig(w_bits=8, x_bits=8, adc_bits=5, m_columns=31)
-    cfg = dataclasses.replace(
-        SMOKE, dtype=jnp.float32,
-        mf=MFTechniqueConfig(mode="cim_sim", cim=cim))
+def _measure_multidevice(devices, ticks: int) -> dict:
+    """Steady-state aggregate decode tok/s of a data-parallel serve mesh
+    over ``devices`` vs the same engine on the default device alone."""
+    cfg = _traffic_cfg(True)
     params = T.lm_init(jax.random.PRNGKey(0), cfg)
-    fleet = Fleet(n_macros=4096, cfg=cim)
-    slots, ticks = 8, int(sys.argv[1])
+    fleet = Fleet(n_macros=4096, cfg=cfg.mf.cim)
+    slots = 8
+    data = max(d for d in (8, 4, 2, 1) if d <= len(devices))
 
     def tok_s(eng):
         for _ in range(eng.slots):
@@ -216,21 +203,48 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
             times.append(time.perf_counter() - t0)
         return eng.slots * ticks / float(np.median(times))
 
-    single = ServeEngine(params, cfg, slots=slots, max_len=128,
-                         fleet=fleet)
+    single = ServeEngine(params, cfg, slots=slots, max_len=128, fleet=fleet)
     t_single = tok_s(single)
-    meshed = ServeEngine(params, cfg, slots=slots, max_len=128,
-                         fleet=fleet)
-    info = shard_engine(meshed, make_serve_mesh(data=4, fleet=1))
+    meshed = ServeEngine(params, cfg, slots=slots, max_len=128, fleet=fleet)
+    info = shard_engine(meshed, make_serve_mesh(
+        data=data, fleet=1, devices=devices[:data]))
     t_mesh = tok_s(meshed)
-    print("MULTIDEV_RESULT " + json.dumps({
-        "devices": jax.device_count(), "slots": slots, "ticks": ticks,
-        "single_tok_s": t_single, "mesh_tok_s": t_mesh,
-        "speedup": t_mesh / t_single, "shard_info": info}))
+    return {"devices": data, "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind, "slots": slots,
+            "ticks": ticks, "single_tok_s": t_single, "mesh_tok_s": t_mesh,
+            "speedup": t_mesh / t_single, "shard_info": info}
+
+
+# The CPU rehearsal child: four forced host devices, pinned to the CPU
+# backend so it never contends for an accelerator.
+REHEARSAL_SCRIPT = textwrap.dedent("""
+    import json, sys
+    sys.path.insert(0, "src")
+    import jax
+    from benchmarks.traffic_report import _measure_multidevice
+    print("MULTIDEV_RESULT " + json.dumps(
+        _measure_multidevice(jax.devices(), int(sys.argv[1]))))
 """)
 
 
 def _multidevice_scaling(quick: bool) -> dict:
+    """Multi-device serving throughput.
+
+    A process that may hold an accelerator measures in-process over the
+    devices it has (one process per chip: a child would contend for
+    them); with a single device there is nothing to measure. A CPU-only
+    process (``JAX_PLATFORMS=cpu``) rehearses in a CPU child on four
+    forced host devices instead, gated >= 1.5x when the host has the
+    cores to show parallel speedup."""
+    ticks = 8 if quick else 24
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        devices = jax.devices()
+        if len(devices) < 2:
+            return {"devices": 1, "platform": devices[0].platform,
+                    "device_kind": devices[0].device_kind,
+                    "measured": False, "gate_1_5x": "single_device"}
+        return dict(_measure_multidevice(devices, ticks), measured=True,
+                    gate_1_5x="not_gated_in_process")
     cpu_count = len(os.sched_getaffinity(0)) if hasattr(
         os, "sched_getaffinity") else (os.cpu_count() or 1)
     capable = cpu_count >= 2
@@ -243,15 +257,17 @@ def _multidevice_scaling(quick: bool) -> dict:
             f"BENCH_TRAFFIC_REQUIRE_MULTIDEV=1 but this host exposes only "
             f"{cpu_count} core(s) — the >=1.5x multi-device gate would be "
             f"vacuous")
-    ticks = 8 if quick else 24
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
     r = subprocess.run(
-        [sys.executable, "-c", MULTIDEV_SCRIPT, str(ticks)],
-        capture_output=True, text=True, timeout=1200,
+        [sys.executable, "-c", REHEARSAL_SCRIPT, str(ticks)],
+        capture_output=True, text=True, timeout=1200, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     line = next((ln for ln in r.stdout.splitlines()
                  if ln.startswith("MULTIDEV_RESULT ")), None)
     assert line is not None, r.stdout + r.stderr
     result = json.loads(line[len("MULTIDEV_RESULT "):])
+    result["measured"] = True
     result["cpu_count"] = cpu_count
     result["host_parallel_capable"] = capable
     if capable:
@@ -342,13 +358,15 @@ def run(quick: bool = True):
                  f"single_device_bitwise={parity} "
                  f"cache_leaves={shard_info['cache_sharded_leaves']}"))
     rows.append(("traffic_multidevice", 0.0,
-                 f"speedup={multidev['speedup']:.2f}x "
-                 f"gate={multidev['gate_1_5x']} "
-                 f"cpus={multidev['cpu_count']}"))
+                 f"devices={multidev['devices']} "
+                 f"speedup={multidev.get('speedup', float('nan')):.2f}x "
+                 f"gate={multidev['gate_1_5x']}"))
     return rows
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small qwen3 smoke shapes (CI)")

@@ -4,13 +4,16 @@ The static rules (R001-R006) catch the bug *shapes*; this module checks
 the bitwise contracts themselves while an engine serves:
 
 * **Shadow execution** — every decode tick re-runs from the SAME inputs
-  (exec tree aside) through the reference einsum datapath
-  (``use_kernel=False``, plane-level programmed state) and asserts the
-  sampled tokens AND the logits are bitwise identical to the primary
-  path. Identical integer ADC codes imply identical recombines, so any
-  drift here means a broken exactness proof — exactly the class the
+  with each programmed projection paired with its reference-route twin
+  (``use_kernel=False``, plane-level programmed state): on every call the
+  integer ADC code sums of the serving route (fused kernel, lossless
+  collapse, silicon kernel) must equal the einsum reference's bitwise.
+  Any difference means a broken exactness proof — exactly the class the
   PR 7 sigma>0 parity gate guards, but live, against the engine's real
-  silicon state and cache.
+  silicon state and cache. The check sits at the partials, not the
+  logits: equal integer partials are the contract, while the float model
+  around them may round differently in two separately compiled programs
+  (it does on a TPU, where fusion and matmul passes differ per program).
 * **NaN / saturation tripwires** — :func:`repro.core.cim.adc_codes`
   stages a debug callback per conversion while armed; a conversion
   tensor containing NaN, or sitting entirely at full scale (the ADC
@@ -29,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -116,6 +120,20 @@ def _tree_nodes(tree: Any, cls: type) -> list[Any]:
     return found
 
 
+@partial(jax.jit, static_argnames="last_axis_sum")
+def _quanta_stats(arr: jax.Array, last_axis_sum: bool):
+    """(finite, on-grid, largest per-conversion quanta) of one operand,
+    reduced on the device so a full-width fold never round-trips to the
+    host. Scaling by 2^CAP_FIXED_BITS is exact in float32 (a power-of-
+    two exponent shift), so ``round(q) == q`` tests the grid exactly."""
+    import jax.numpy as jnp
+    from repro.core.cim import CAP_FIXED_BITS
+    q = arr.astype(jnp.float32) * jnp.float32(2.0 ** CAP_FIXED_BITS)
+    per_conv = jnp.sum(q, axis=-1) if last_axis_sum else q
+    return (jnp.all(jnp.isfinite(q)), jnp.all(jnp.round(q) == q),
+            jnp.max(per_conv, initial=0.0))
+
+
 def check_cap_quanta(exec_params: Any) -> None:
     """Assert the cap_fixed integer-quanta invariant over an exec tree.
 
@@ -127,60 +145,107 @@ def check_cap_quanta(exec_params: Any) -> None:
     """
     from repro.core.cim import (CAP_FIXED_BITS, CimKernelSilicon,
                                 ProjectionSilicon, cap_fixed)
-    scale = 2.0 ** CAP_FIXED_BITS
     budget = 2.0 ** 24
 
-    def must_be_quanta(arr: jax.Array, what: str) -> np.ndarray:
-        q = np.asarray(arr, dtype=np.float64) * scale
-        if not np.all(np.isfinite(q)):
+    def must_be_quanta(arr: jax.Array, what: str,
+                       last_axis_sum: bool = False) -> float:
+        finite, on_grid, peak = _quanta_stats(arr, last_axis_sum)
+        if not bool(finite):
             raise SanitizeError(f"{what}: non-finite silicon operand")
-        if np.max(np.abs(q - np.round(q)), initial=0.0) != 0.0:
+        if not bool(on_grid):
             raise SanitizeError(
                 f"{what}: values are off the 2^-{CAP_FIXED_BITS} "
                 f"fixed-point grid — float32 contraction order is no "
                 f"longer provably irrelevant")
-        return q
+        return float(peak)
 
     for sil in _tree_nodes(exec_params, ProjectionSilicon):
         for name in ("cap", "rx_cap"):
-            snapped = cap_fixed(getattr(sil, name))
-            q = must_be_quanta(snapped, f"ProjectionSilicon.{name}")
-            per_conv = np.sum(q, axis=-1)  # quanta per chunk conversion
-            if np.max(per_conv, initial=0.0) >= budget:
+            # quanta per chunk conversion
+            peak = must_be_quanta(cap_fixed(getattr(sil, name)),
+                                  f"ProjectionSilicon.{name}",
+                                  last_axis_sum=True)
+            if peak >= budget:
                 raise SanitizeError(
                     f"ProjectionSilicon.{name}: a conversion denominator "
-                    f"reaches {np.max(per_conv):.3g} quanta >= 2^24 — "
+                    f"reaches {peak:.3g} quanta >= 2^24 — "
                     f"float32 partial sums can round")
     for silk in _tree_nodes(exec_params, CimKernelSilicon):
         for name in ("wpc", "gwc", "rxp"):
             must_be_quanta(getattr(silk, name), f"CimKernelSilicon.{name}")
         for name in ("den", "rx_den"):
-            q = must_be_quanta(getattr(silk, name),
-                               f"CimKernelSilicon.{name}")
-            if np.max(q, initial=0.0) >= budget:
+            peak = must_be_quanta(getattr(silk, name),
+                                  f"CimKernelSilicon.{name}")
+            if peak >= budget:
                 raise SanitizeError(
                     f"CimKernelSilicon.{name}: a conversion denominator "
-                    f"reaches {np.max(q):.3g} quanta >= 2^24 — float32 "
+                    f"reaches {peak:.3g} quanta >= 2^24 — float32 "
                     f"partial sums can round")
+
+
+# Key under which the sanitizer pairs each programmed projection node with
+# its reference-route twin (read by ``repro.core.mf.apply_projection``).
+SANITIZE_REF = "sanitize_ref"
+
+# Per-projection partial mismatch counts staged by check_partials, drained
+# by ServeSanitizer.check_step after the step that produced them.
+_PARTIALS_LOG: list[int] = []
+
+
+def check_partials(x: jax.Array, node: dict, cfg) -> None:
+    """Stage, under trace, the exactness check of one paired projection:
+    its integer code sums down the serving route against the plane-level
+    einsum reference (``node[SANITIZE_REF]``) on the SAME input, counted
+    on the device and logged per call.
+
+    The partials are integer-valued floats under every route, so the
+    comparison is exact within one program whatever the backend's matmul
+    precision or fusion choices; recombined float outputs of two
+    separately compiled programs carry no such guarantee."""
+    import jax.numpy as jnp
+    from repro.core.programmed import projection_partials
+    x2 = x.reshape(-1, x.shape[-1])
+    got = projection_partials(x2, node, cfg)
+    want = projection_partials(x2, node[SANITIZE_REF],
+                               dataclasses.replace(cfg, use_kernel=False))
+    n_diff = sum(jnp.sum(g != w) for g, w in zip(got, want))
+    jax.debug.callback(lambda n: _PARTIALS_LOG.append(int(n)), n_diff,
+                       ordered=True)
+
+
+def paired_exec(primary: Any, reference: Any) -> Any:
+    """``primary`` with every programmed linear projection node carrying
+    its ``reference`` twin under :data:`SANITIZE_REF` (same names: both
+    trees come from the same parameter tree)."""
+    from repro.core.programmed import iter_projections, map_projections
+    refs = {name: node for name, node, _ in iter_projections(reference)}
+
+    def pair(name, node, kind):
+        if "prog" not in node:
+            return node
+        ref = refs[name]
+        return dict(node, **{SANITIZE_REF: {
+            k: ref[k] for k in ("w", "prog", "sil") if k in ref}})
+
+    return map_projections(primary, pair)
 
 
 class ServeSanitizer:
     """Shadow-execution harness attached to a :class:`ServeEngine`.
 
-    Owns a reference-datapath twin of the engine's config (fused kernel
-    off, lossless collapse off → the plane-level einsum pipeline), a
-    shadow programmed/exec tree kept in sync through the engine's
-    refresh path, and a jitted shadow step. ``check_step`` replays the
-    tick and compares bitwise.
+    Owns a reference-route programmed/exec tree (fused kernel off,
+    lossless collapse off → the plane-level einsum pipeline) kept in sync
+    through the engine's refresh path, and a jitted step of the engine's
+    own config over the engine's exec tree paired with it.
+    ``check_step`` replays the tick through that step: every projection
+    computes its integer code sums down both routes on the same input
+    and any difference raises.
     """
 
     def __init__(self, engine, temperature: float = 0.0):
         from repro.serve.engine import make_serve_step
-        cim = dataclasses.replace(engine.cfg.mf.cim, use_kernel=False)
-        mf = dataclasses.replace(engine.cfg.mf, cim=cim)
-        self.cfg = dataclasses.replace(engine.cfg, mf=mf)
-        self._cim = cim
-        self.step_fn = jax.jit(make_serve_step(self.cfg,
+        self._cim = dataclasses.replace(engine.cfg.mf.cim, use_kernel=False)
+        self.step_fn = jax.jit(make_serve_step(engine.cfg,
                                                temperature=temperature))
         self._programmed_src: Optional[int] = None
         self._shadow_programmed = None
@@ -213,34 +278,33 @@ class ServeSanitizer:
         check_cap_quanta(self.shadow_exec)
 
     def check_step(self, engine, cache_before, tokens, rng, step,
-                   nxt, logits) -> None:
-        """Replay one decode tick through the reference datapath and
-        assert bitwise agreement; then inspect the tripwire log."""
-        s_nxt, s_logits, _ = self.step_fn(self.shadow_exec, cache_before,
-                                          tokens, rng, step)
-        h_logits = np.asarray(logits)
-        hs_logits = np.asarray(s_logits)
-        if np.any(np.isnan(h_logits)):
+                   logits) -> None:
+        """Replay one decode tick with every projection's partials checked
+        against the reference route; then inspect the tripwire log."""
+        _PARTIALS_LOG.clear()
+        self.step_fn(paired_exec(engine._exec_params, self.shadow_exec),
+                     cache_before, tokens, rng, step)
+        jax.effects_barrier()
+        if np.any(np.isnan(np.asarray(logits))):
             raise _finding(
                 f"NaN logits at stream step {int(step)} on the primary "
                 f"datapath", check="nan_logits", stream=int(step))
-        if not np.array_equal(h_logits, hs_logits):
-            bad = int(np.sum(h_logits != hs_logits))
-            i = np.unravel_index(
-                int(np.argmax(h_logits != hs_logits)), h_logits.shape)
+        counts = list(_PARTIALS_LOG)
+        if not counts:
+            raise _finding(
+                f"no projection partials were checked at stream step "
+                f"{int(step)} — the exec tree holds no programmed "
+                f"projection", check="no_partials", stream=int(step))
+        bad = [n for n in counts if n]
+        if bad:
             raise _finding(
                 f"fused/einsum divergence at stream step {int(step)}: "
-                f"{bad} logit(s) differ, first at {tuple(i)} "
-                f"(primary {h_logits[i]!r} vs reference {hs_logits[i]!r})"
-                f" — the exactness contract between the Pallas kernel "
-                f"path and the reference einsums is broken",
-                check="logit_divergence", stream=int(step), n_diff=bad)
-        if not np.array_equal(np.asarray(nxt), np.asarray(s_nxt)):
-            raise _finding(
-                f"sampled-token divergence at stream step {int(step)} "
-                f"despite equal logits — RNG threading differs between "
-                f"primary and shadow steps",
-                check="token_divergence", stream=int(step))
+                f"{sum(bad)} integer partial(s) differ in {len(bad)} of "
+                f"{len(counts)} projection calls — the exactness contract "
+                f"between the serving datapath and the reference einsums "
+                f"is broken",
+                check="partial_divergence", stream=int(step),
+                n_diff=sum(bad))
         for nan_frac, sat_frac in drain_tripwires():
             if nan_frac > 0.0:
                 raise _finding(

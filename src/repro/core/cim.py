@@ -24,7 +24,7 @@ The datapath is split along the hardware's program-time / step-time
 boundary: ``cim_program_weight_state`` / ``cim_program_kernel_state`` do
 everything that depends only on the weights (quantise, sign/magnitude
 decompose, chunk or kernel-pack, digital residue) and the step-time passes
-(``cim_input_partials`` / ``cim_kernel_forward``) consume that frozen
+(``cim_input_partials`` / ``cim_kernel_partials``) consume that frozen
 state, computing only the input-side work per call. ``cim_mf_partials`` /
 ``cim_mf_matmul`` compose both phases on the fly; ``core/programmed.py``
 builds persistent programmed state for weight-stationary serving.
@@ -152,6 +152,15 @@ def adc_quantize(mav: jax.Array, adc_bits: int,
 # (the sigma>0 kernel-vs-reference parity gate of BENCH_silicon.json).
 CAP_FIXED_BITS = 14
 
+# Matmul precision of every contraction whose operands leave the bf16
+# grid: 2^-14-grid caps, gain-trimmed bit streams, and code sums past
+# 256 (the chunk axis is summed before the plane-weight dot). A TPU runs
+# an f32 dot at DEFAULT precision as one bf16 pass, which rounds those
+# operands; HIGHEST keeps the f32 products the exactness proofs assume.
+# Pure {0,1} x {0,1} (or <= 127 integer) contractions are exact in bf16
+# and keep the default.
+EXACT = jax.lax.Precision.HIGHEST
+
 
 def cap_fixed(cap: jax.Array) -> jax.Array:
     """Quantise cap-DAC weights to the 2^-CAP_FIXED_BITS fixed-point grid.
@@ -165,8 +174,8 @@ def cap_fixed(cap: jax.Array) -> jax.Array:
     return jnp.round(cap.astype(jnp.float32) * s) / s
 
 
-def _weight_operands(w: jax.Array, cfg: CimConfig, sw: jax.Array):
-    """Quantise the weight operand and decompose into sign gates + planes.
+def _weight_sign_magnitude(w: jax.Array, cfg: CimConfig, sw: jax.Array):
+    """Quantise the weight operand into sign gates + integer magnitudes.
 
     Sign bits are stored SEPARATELY from the magnitude planes in the
     µArray (sign row + W_P-1 magnitude rows), so they come from the
@@ -174,13 +183,20 @@ def _weight_operands(w: jax.Array, cfg: CimConfig, sw: jax.Array):
     keeps its true sign bit (quantising first would flip small negative
     weights to +, a large systematic error at low W_P).
 
-    Returns (step_w, abs_w, w_planes): {0,1} sign gates (K, N), integer
-    magnitudes (K, N), and the (Pw, K, N) bitplane stack (LSB first).
+    Returns (step_w, abs_w): {0,1} sign gates (K, N) and integer
+    magnitudes (K, N).
     """
     wq = quant.quantize(w, sw, cfg.w_bits)          # (K, N) int
     step_w = (w >= 0).astype(jnp.float32)           # (K, N)
-    abs_w = jnp.abs(wq)
-    w_planes = quant.bitplanes(abs_w, cfg.w_bits)   # (Pw, K, N)
+    return step_w, jnp.abs(wq)
+
+
+def _weight_operands(w: jax.Array, cfg: CimConfig, sw: jax.Array,
+                     planes_dtype=jnp.float32):
+    """:func:`_weight_sign_magnitude` plus the (Pw, K, N) magnitude
+    bitplane stack (LSB first) of ``planes_dtype``."""
+    step_w, abs_w = _weight_sign_magnitude(w, cfg, sw)
+    w_planes = quant.bitplanes(abs_w, cfg.w_bits, planes_dtype)  # (Pw, K, N)
     return step_w, abs_w, w_planes
 
 
@@ -321,10 +337,10 @@ def cim_program_weight_state(w: jax.Array, cfg: CimConfig,
                              sw: jax.Array) -> CimWeightState:
     """Program-time pass: quantise/decompose/chunk the weights once."""
     K, N = w.shape
-    step_w, abs_w, w_planes = _weight_operands(w, cfg, sw)
+    step_w, abs_w, w_planes = _weight_operands(w, cfg, sw, jnp.int8)
     m = cfg.m_columns
     wp = _chunk(jnp.moveaxis(w_planes, -1, 0), m, K)             # (N, Pw, C, m)
-    wt = jnp.transpose(wp, (2, 3, 0, 1)).astype(jnp.int8)        # (C, m, N, Pw)
+    wt = jnp.transpose(wp, (2, 3, 0, 1))                         # (C, m, N, Pw)
     gw = _chunk(step_w.T, m, K)                                  # (N, C, m)
     gwt = jnp.transpose(gw, (1, 2, 0)).astype(jnp.int8)          # (C, m, N)
     # exact-ok: integer |w_q| magnitudes, column sums below 2^24 — exact in f32
@@ -422,14 +438,15 @@ def cim_input_partials(x2: jax.Array, ws: CimWeightState, cfg: CimConfig,
                              ws.wt.astype(jnp.float32))
         codes1 = adc(counts1 / inv)                              # (C, B, N, Pw)
         # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-        s1c = jnp.einsum("cbnp,p->bn", codes1, pw)
+        s1c = jnp.einsum("cbnp,p->bn", codes1, pw, precision=EXACT)
         # S2 = sum_k step(w_kn) * |x|_k  (Eq. 2a numerator)
         # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
         counts2 = jnp.einsum("qbcm,cmn->cqbn", xp,
-                             ws.gwt.astype(jnp.float32))
+                             ws.gwt.astype(jnp.float32),
+                             precision=None if dac_gains is None else EXACT)
         codes2 = adc(counts2 / inv)                              # (C, Px, B, N)
         # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-        s2c = jnp.einsum("cqbn,q->bn", codes2, px)
+        s2c = jnp.einsum("cqbn,q->bn", codes2, px, precision=EXACT)
         # R_x via the dummy all-ones row (shared across weight vectors).
         rxc = _nominal_rx(xp, cfg)                               # (B, 1)
         return CimPartials(s1c, s2c, rxc, ws.r_w)
@@ -449,20 +466,21 @@ def cim_input_partials(x2: jax.Array, ws: CimWeightState, cfg: CimConfig,
                        (2, 3, 0, 1))                             # (N, Pw, C, m)
     gw = jnp.transpose(ws.gwt.astype(jnp.float32), (2, 0, 1))    # (N, C, m)
     # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
-    num1 = jnp.einsum("bcm,npcm,cm->bnpc", gx, wp, cap)
+    num1 = jnp.einsum("bcm,npcm,cm->bnpc", gx, wp, cap, precision=EXACT)
     codes1 = adc(num1 / cap_sum[None, None, None, :])            # (B, N, Pw, C)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    s1c = jnp.einsum("bnpc,p->bn", codes1, pw)
+    s1c = jnp.einsum("bnpc,p->bn", codes1, pw, precision=EXACT)
     # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
-    num2 = jnp.einsum("pbcm,ncm,cm->pbnc", xp, gw, cap)
+    num2 = jnp.einsum("pbcm,ncm,cm->pbnc", xp, gw, cap, precision=EXACT)
     codes2 = adc(num2 / cap_sum[None, None, None, :])            # (Px, B, N, C)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    s2c = jnp.einsum("pbnc,p->bn", codes2, px)
+    s2c = jnp.einsum("pbnc,p->bn", codes2, px, precision=EXACT)
     # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
-    num_rx = jnp.einsum("pbcm,cm->pbc", xp, cap)
+    num_rx = jnp.einsum("pbcm,cm->pbc", xp, cap, precision=EXACT)
     codes_rx = adc(num_rx / cap_sum[None, None, :])              # (Px, B, C)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    rxc = jnp.einsum("pbc,p->b", codes_rx, px)[:, None]          # (B, 1)
+    rxc = jnp.einsum("pbc,p->b", codes_rx, px,
+                     precision=EXACT)[:, None]                   # (B, 1)
     return CimPartials(s1c, s2c, rxc, ws.r_w)
 
 
@@ -491,7 +509,7 @@ def _silicon_partials(gx: jax.Array, xp: jax.Array, ws: CimWeightState,
                        (2, 3, 0, 1))                             # (N, Pw, C, m)
     gw = jnp.transpose(ws.gwt.astype(jnp.float32), (2, 0, 1))    # (N, C, m)
     # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
-    num1 = jnp.einsum("bcm,npcm,ncm->bnpc", gx, wp, cap)
+    num1 = jnp.einsum("bcm,npcm,ncm->bnpc", gx, wp, cap, precision=EXACT)
     off1 = off[:, None, :]
     d1 = sil.dither(num1.shape, 1)
     if d1 is not None:
@@ -499,16 +517,16 @@ def _silicon_partials(gx: jax.Array, xp: jax.Array, ws: CimWeightState,
     codes1 = adc_codes(num1 / cap_sum[:, None, :], cfg.adc_bits,
                        off1)                                     # (B, N, Pw, C)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    s1c = jnp.einsum("bnpc,p->bn", codes1, pw)
+    s1c = jnp.einsum("bnpc,p->bn", codes1, pw, precision=EXACT)
     # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
-    num2 = jnp.einsum("qbcm,ncm,ncm->qbnc", xp, gw, cap)
+    num2 = jnp.einsum("qbcm,ncm,ncm->qbnc", xp, gw, cap, precision=EXACT)
     off2 = off
     d2 = sil.dither(num2.shape, 2)
     if d2 is not None:
         off2 = off2 + d2
     codes2 = adc_codes(num2 / cap_sum, cfg.adc_bits, off2)       # (Px, B, N, C)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    s2c = jnp.einsum("qbnc,q->bn", codes2, px)
+    s2c = jnp.einsum("qbnc,q->bn", codes2, px, precision=EXACT)
     rxc = _silicon_rx(xp, cfg, sil)                              # (B, 1)
     return CimPartials(s1c, s2c, rxc, ws.r_w)
 
@@ -521,7 +539,7 @@ def _silicon_rx(xp: jax.Array, cfg: CimConfig, sil: ProjectionSilicon
     # exact-ok: 2^-14-grid caps; small fixed-point chunk sums — exact in f32
     rx_sum = jnp.sum(rx_cap, axis=-1)                            # (C,)
     # exact-ok: {0,1} bits x 2^-14-grid caps; integer quanta < 2^24 — exact in f32
-    num_rx = jnp.einsum("qbcm,cm->qbc", xp, rx_cap)
+    num_rx = jnp.einsum("qbcm,cm->qbc", xp, rx_cap, precision=EXACT)
     off_rx = sil.rx_offset.astype(jnp.float32)
     d_rx = sil.dither(num_rx.shape, 3)
     if d_rx is not None:
@@ -529,7 +547,8 @@ def _silicon_rx(xp: jax.Array, cfg: CimConfig, sil: ProjectionSilicon
     codes_rx = adc_codes(num_rx / rx_sum, cfg.adc_bits,
                          off_rx)                                 # (Px, B, C)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    return jnp.einsum("qbc,q->b", codes_rx, px)[:, None]         # (B, 1)
+    return jnp.einsum("qbc,q->b", codes_rx, px,
+                      precision=EXACT)[:, None]                  # (B, 1)
 
 
 def _nominal_rx(xp: jax.Array, cfg: CimConfig) -> jax.Array:
@@ -545,7 +564,8 @@ def _nominal_rx(xp: jax.Array, cfg: CimConfig) -> jax.Array:
     codes_rx = adc_codes(counts_rx / jnp.float32(cfg.m_columns),
                          cfg.adc_bits)
     # exact-ok: integer ADC codes x power-of-two plane weights — exact in f32
-    return jnp.einsum("pbc,p->b", codes_rx, px)[:, None]         # (B, 1)
+    return jnp.einsum("pbc,p->b", codes_rx, px,
+                      precision=EXACT)[:, None]                  # (B, 1)
 
 
 def cim_rx_partials(x2: jax.Array, cfg: CimConfig, sx: jax.Array,
@@ -616,24 +636,37 @@ class CimKernelState(NamedTuple):
     so it is hoisted here too and step time packs only the input planes.
     """
 
-    gw_packed: jax.Array   # (N, Kp) chunk-packed step(w) gates (step_w.T)
-    wp_packed: jax.Array   # (Pw, Kp, N) chunk-packed |w| magnitude planes
+    gw_packed: jax.Array   # (Kp, N) int8 chunk-packed step(w) gates
+    wp_packed: jax.Array   # (Pw, Kp, N) int8 chunk-packed |w| planes
     r_w: jax.Array         # (1, N) exact digital sum_k |w_q|_kn
-    rx_gates: Optional[jax.Array] = None   # (1, Kp) packed dummy-row gates
+    rx_gates: Optional[jax.Array] = None   # (Kp, 1) packed dummy-row gates
 
 
 def cim_program_kernel_state(w: jax.Array, cfg: CimConfig,
                              sw: jax.Array) -> CimKernelState:
-    """Program-time pass for the fused Pallas path (pre-packed layout)."""
+    """Program-time pass for the fused Pallas path (pre-packed layout).
+
+    The {0,1} cells are stored as int8 (the kernel widens them to f32 in
+    VMEM, exact on {0,1}): (Pw + 1) * Kp/K bytes per weight, ~9 B at
+    W_P=8 and K=1024 (Kp=1152), against ~36 B as f32 — the difference
+    between a 28-layer qwen3-0.6b fitting one 16 GB chip or not.
+    """
     from repro.kernels import ops as kops
     K = w.shape[0]
-    step_w, abs_w, w_planes = _weight_operands(w, cfg, sw)
-    gw_packed = kops.pack_chunks(step_w.T, cfg.m_columns)
+    step_w, abs_w, w_planes = _weight_operands(w, cfg, sw, jnp.int8)
+    gw_packed = kops.pack_planes(step_w[None].astype(jnp.int8),
+                                 cfg.m_columns)[0]
     wp_packed = kops.pack_planes(w_planes, cfg.m_columns)
     # exact-ok: integer |w_q| magnitudes, column sums below 2^24 — exact in f32
     r_w = jnp.sum(abs_w, axis=0).astype(jnp.float32)[None, :]
-    rx_gates = kops.pack_chunks(jnp.ones((1, K), jnp.float32), cfg.m_columns)
-    return CimKernelState(gw_packed, wp_packed, r_w, rx_gates)
+    return CimKernelState(gw_packed, wp_packed, r_w, _rx_gates(K, cfg))
+
+
+def _rx_gates(k: int, cfg: CimConfig) -> jax.Array:
+    """The all-ones dummy row, chunk-packed as a (Kp, 1) static operand."""
+    from repro.kernels import ops as kops
+    return kops.pack_planes(jnp.ones((1, k, 1), jnp.float32),
+                            cfg.m_columns)[0]
 
 
 def cim_kernel_state_from_weight_state(ws: CimWeightState,
@@ -647,25 +680,30 @@ def cim_kernel_state_from_weight_state(ws: CimWeightState,
     """
     from repro.kernels import ops as kops
     m = cfg.m_columns
-    wp = jnp.transpose(ws.wt.astype(jnp.float32), (3, 2, 0, 1))  # (Pw,N,C,m)
+    wp = jnp.transpose(ws.wt.astype(jnp.int8), (3, 2, 0, 1))     # (Pw,N,C,m)
     wp_packed = jnp.moveaxis(kops.pack_chunked(wp, m), 1, -1)    # (Pw,Kp,N)
-    gw = jnp.transpose(ws.gwt.astype(jnp.float32), (2, 0, 1))    # (N, C, m)
-    gw_packed = kops.pack_chunked(gw, m)                         # (N, Kp)
+    gw = jnp.transpose(ws.gwt.astype(jnp.int8), (2, 0, 1))       # (N, C, m)
+    gw_packed = kops.pack_chunked(gw, m).T                       # (Kp, N)
     return CimKernelState(gw_packed, wp_packed, ws.r_w)
 
 
-def cim_kernel_forward(x2: jax.Array, ks: CimKernelState, cfg: CimConfig,
-                       sw: jax.Array, sx: jax.Array,
-                       dac_gains: Optional[jax.Array] = None) -> jax.Array:
+def cim_kernel_partials(x2: jax.Array, ks: CimKernelState, cfg: CimConfig,
+                        sx: jax.Array,
+                        dac_gains: Optional[jax.Array] = None) -> CimPartials:
     """Step-time fused Pallas pass against programmed kernel state.
 
-    Per-chunk MAV + ADC + plane recombination without materialising the
-    MAV tensor; only the streaming input side is packed per call (the
+    Per-chunk MAV + ADC + plane-weighted code sums without materialising
+    the MAV tensor; only the streaming input side is packed per call (the
     weight gates/planes AND the all-ones dummy-row gates were packed at
-    program time). Recombines through :func:`cim_mf_recombine`, so the
-    output is bitwise identical to the einsum fast path. Silicon-injected
+    program time). The integer code sums equal the einsum path's bit for
+    bit; recombine with :func:`cim_mf_recombine`. Silicon-injected
     projections do not come through here — they take the fused
-    :func:`cim_kernel_silicon_partials` route via ``cim_input_partials``.
+    :func:`cim_kernel_silicon_partials` route.
+
+    The input planes stream as the kernel's gates, batch rows second-minor
+    like ``x2`` itself: no operand asks for a transposed input, so the
+    float ops that produce ``x2`` compile to the same layouts (and round
+    the same) as on every other route.
     """
     from repro.kernels import ops as kops
     K = x2.shape[-1]
@@ -675,17 +713,17 @@ def cim_kernel_forward(x2: jax.Array, ks: CimKernelState, cfg: CimConfig,
     if dac_gains is not None:
         x_planes = x_planes * dac_gains.astype(jnp.float32)
     gx = kops.pack_chunks(step_x, m)                             # (B, Kp)
-    xp = kops.pack_planes(jnp.moveaxis(x_planes, 1, -1), m)      # (Px, Kp, B)
+    xp = kops.pack_chunks(x_planes, m)                           # (Px, B, Kp)
     rx_gates = ks.rx_gates
     if rx_gates is None:
-        rx_gates = kops.pack_chunks(jnp.ones((1, K), jnp.float32), m)
+        rx_gates = _rx_gates(K, cfg)
     s1c = kops.cim_mav_packed(gx, ks.wp_packed, m_columns=m,
                               adc_bits=cfg.adc_bits)             # (B, N)
-    s2c = kops.cim_mav_packed(ks.gw_packed, xp, m_columns=m,
-                              adc_bits=cfg.adc_bits).T           # (B, N)
-    rxc = kops.cim_mav_packed(rx_gates, xp, m_columns=m,
-                              adc_bits=cfg.adc_bits).T           # (B, 1)
-    return cim_mf_recombine(CimPartials(s1c, s2c, rxc, ks.r_w), sw, sx, cfg)
+    s2c = kops.cim_mav_packed(xp, ks.gw_packed[None], m_columns=m,
+                              adc_bits=cfg.adc_bits)             # (B, N)
+    rxc = kops.cim_mav_packed(xp, rx_gates[None], m_columns=m,
+                              adc_bits=cfg.adc_bits)             # (B, 1)
+    return CimPartials(s1c, s2c, rxc, ks.r_w)
 
 
 class CimKernelSilicon(NamedTuple):
@@ -751,7 +789,7 @@ def cim_program_silicon(ks: CimKernelState, sil: ProjectionSilicon,
     capq = cap_fixed(sil.cap)                                    # (...,N,C,m)
     capk = jnp.swapaxes(kops.pack_chunked(capq, m), -1, -2)      # (...,Kp,N)
     wpc = ks.wp_packed.astype(jnp.float32) * capk[..., None, :, :]
-    gwc = jnp.swapaxes(ks.gw_packed.astype(jnp.float32), -1, -2) * capk
+    gwc = ks.gw_packed.astype(jnp.float32) * capk
     # exact-ok: 2^-14-grid caps; small fixed-point chunk sums — exact in f32
     den = _pad_axis(jnp.swapaxes(jnp.sum(capq, -1), -1, -2), -2, cpad, 1.0)
     off = _pad_axis(jnp.swapaxes(sil.offset.astype(jnp.float32), -1, -2),
@@ -837,7 +875,8 @@ def cim_mf_matmul(x: jax.Array, w: jax.Array, cfg: CimConfig,
             and silicon is None:
         # Fused Pallas path (no variability injection).
         ks = cim_program_kernel_state(w, cfg, sw)
-        y = cim_kernel_forward(x2, ks, cfg, sw, sx)
+        y = cim_mf_recombine(cim_kernel_partials(x2, ks, cfg, sx), sw, sx,
+                             cfg)
         return y.reshape(batch_shape + (N,)).astype(x.dtype)
 
     parts = cim_mf_partials(x2, w, cfg, sw, sx, cap_weights,

@@ -258,6 +258,11 @@ def apply_projection(params: dict, x: jax.Array, mode: ExecMode | str,
             # .attach_silicon embeds them as "sil", riding scans exactly
             # like the programmed state they perturb).
             sil = params.get("sil")
+            # Fence the projection: the float ops that make x and consume
+            # y then compile to the same fusions whichever route serves it
+            # (lossless collapse, plane einsum, fused kernel), so they round
+            # alike and the routes' tokens agree bitwise on a TPU too.
+            x = jax.lax.optimization_barrier(x)
             if isinstance(prog, SwappedMacro):
                 # Fleet too small to pin this projection: round-interleaved
                 # execution re-programs tiles per input stream.
@@ -266,6 +271,19 @@ def apply_projection(params: dict, x: jax.Array, mode: ExecMode | str,
                 y = cim_mf_matmul_programmed(x, prog, cim_cfg, silicon=sil,
                                              silicon_kernel=params.get(
                                                  "silk"))
+            from repro.analysis import sanitize
+            if sanitize.SANITIZE_REF in params:
+                # REPRO_SANITIZE's paired replay step only.
+                sanitize.check_partials(x, params, cim_cfg)
+            y = jax.lax.optimization_barrier(y)
+            if "fleet" in jax.sharding.get_abstract_mesh().axis_names:
+                # Fleet-sharded serving (repro.traffic.shard): each device
+                # holds a slice of the output channels. Gather them, so the
+                # float model downstream reduces whole rows in the same
+                # order as on one device.
+                P = jax.sharding.PartitionSpec
+                y = jax.lax.with_sharding_constraint(
+                    y, P(*[P.UNCONSTRAINED] * (y.ndim - 1), None))
         else:
             y = cim.cim_mf_matmul_ste(x, w, cim_cfg)
         if _calib_tap.error_active():

@@ -41,6 +41,7 @@ EXPERIMENTS.md "Corpus-driven activation calibration".
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -49,8 +50,9 @@ import jax.numpy as jnp
 from repro.core import quant
 from repro.core.cim import (CimConfig, CimKernelSilicon, CimKernelState,
                             CimPartials, CimWeightState, ProjectionSilicon,
-                            _input_operands, _weight_operands, cap_fixed,
-                            cim_input_partials, cim_kernel_forward,
+                            _input_operands, _weight_sign_magnitude,
+                            cap_fixed,
+                            cim_input_partials, cim_kernel_partials,
                             cim_kernel_silicon_partials, cim_mf_recombine,
                             cim_program_kernel_state, cim_program_silicon,
                             cim_program_weight_state, cim_rx_partials)
@@ -229,7 +231,7 @@ def program_macro(w: jax.Array, cfg: CimConfig, *, sx, sw=None,
         return ProgrammedMacro(sw, sx, ks.r_w, None, ks, None, dac_gains)
     _check_packable(cfg)
     if prefer_lossless and adc_exactly_lossless(cfg) and dac_gains is None:
-        step_w, abs_w, _ = _weight_operands(w, cfg, sw)
+        step_w, abs_w = _weight_sign_magnitude(w, cfg, sw)
         # exact-ok: integer |w_q| magnitudes, column sums below 2^24 — exact in f32
         r_w = jnp.sum(abs_w, axis=0).astype(jnp.float32)[None, :]
         packed = (abs_w.astype(jnp.int32)
@@ -261,18 +263,16 @@ def _lossless_partials(x2: jax.Array, ls: CimLosslessState, cfg: CimConfig,
     return CimPartials(s1c, s2c, rxc, r_w)
 
 
-def cim_mf_matmul_programmed(x: jax.Array, prog: ProgrammedMacro,
-                             cfg: CimConfig,
-                             cap_weights: Optional[jax.Array] = None,
-                             comparator_offset: Optional[jax.Array] = None,
-                             silicon: Optional[ProjectionSilicon] = None,
-                             silicon_kernel: Optional[CimKernelSilicon]
-                             = None) -> jax.Array:
-    """Step-time MF correlation x:(...,K) against a programmed macro.
-
-    Bit-identical to ``cim_mf_matmul(x, w, cfg)`` whenever ``prog`` was
-    programmed with the same ``cfg`` and the dynamic activation scale of
-    ``x`` (the parity tested by tests/test_programmed.py).
+def programmed_partials(x2: jax.Array, prog: ProgrammedMacro,
+                        cfg: CimConfig,
+                        cap_weights: Optional[jax.Array] = None,
+                        comparator_offset: Optional[jax.Array] = None,
+                        silicon: Optional[ProjectionSilicon] = None,
+                        silicon_kernel: Optional[CimKernelSilicon] = None
+                        ) -> CimPartials:
+    """Integer code sums of x2:(B, K) against a programmed macro, down
+    whichever route its state holds (plane einsum, fused kernel, lossless
+    collapse); :func:`cim_mf_matmul_programmed` recombines them.
 
     Variability injection: the legacy shared draw (``cap_weights`` /
     ``comparator_offset``) runs on the bit-packed plane-level state
@@ -286,19 +286,17 @@ def cim_mf_matmul_programmed(x: jax.Array, prog: ProgrammedMacro,
     the per-step fold. The collapsed lossless state has no per-chunk ADC
     evaluations to perturb and raises for every injection flavour.
     """
-    K = x.shape[-1]
-    batch_shape = x.shape[:-1]
-    x2 = x.reshape(-1, K)
+    K = x2.shape[-1]
     inject = (cap_weights is not None or comparator_offset is not None
               or silicon is not None)
-    sx_q = prog.sx if prog.dac_gains is None else prog.sx * prog.dac_gains
     if prog.state is not None:
+        sx_q = (prog.sx if prog.dac_gains is None
+                else prog.sx * prog.dac_gains)
         ws = unpack_weight_state(prog.state, cfg)
-        parts = cim_input_partials(x2, ws, cfg, sx_q,
-                                   cap_weights, comparator_offset, silicon,
-                                   dac_gains=prog.dac_gains)
-        y = cim_mf_recombine(parts, prog.sw, prog.sx, cfg)
-    elif prog.kernel is not None:
+        return cim_input_partials(x2, ws, cfg, sx_q, cap_weights,
+                                  comparator_offset, silicon,
+                                  dac_gains=prog.dac_gains)
+    if prog.kernel is not None:
         if cap_weights is not None or comparator_offset is not None:
             raise ValueError(
                 "the legacy shared cap_weights/comparator_offset injection "
@@ -306,23 +304,21 @@ def cim_mf_matmul_programmed(x: jax.Array, prog: ProgrammedMacro,
                 "per-tile `silicon` instances fold into the fused kernel. "
                 "Re-program with use_kernel=False and "
                 "prefer_lossless=False for the legacy knobs.")
-        if silicon is not None:
-            if prog.dac_gains is not None:
-                raise ValueError(
-                    "per-feature DAC gain trims (per-channel sx "
-                    "calibration) do not compose with silicon injection; "
-                    "program per-tensor scales for silicon fleets")
-            silk = silicon_kernel
-            if silk is None:
-                silk = cim_program_silicon(prog.kernel, silicon, cfg,
-                                           n_chunks=-(-K // cfg.m_columns))
-            parts = cim_kernel_silicon_partials(x2, prog.kernel, silk, cfg,
-                                                prog.sx, silicon)
-            y = cim_mf_recombine(parts, prog.sw, prog.sx, cfg)
-        else:
-            y = cim_kernel_forward(x2, prog.kernel, cfg, prog.sw, prog.sx,
-                                   prog.dac_gains)
-    elif inject:
+        if silicon is None:
+            return cim_kernel_partials(x2, prog.kernel, cfg, prog.sx,
+                                       prog.dac_gains)
+        if prog.dac_gains is not None:
+            raise ValueError(
+                "per-feature DAC gain trims (per-channel sx "
+                "calibration) do not compose with silicon injection; "
+                "program per-tensor scales for silicon fleets")
+        silk = silicon_kernel
+        if silk is None:
+            silk = cim_program_silicon(prog.kernel, silicon, cfg,
+                                       n_chunks=-(-K // cfg.m_columns))
+        return cim_kernel_silicon_partials(x2, prog.kernel, silk, cfg,
+                                           prog.sx, silicon)
+    if inject:
         raise ValueError(
             "variability injection needs per-chunk ADC evaluations, but "
             "this macro holds the collapsed exactly-lossless state — its "
@@ -330,10 +326,28 @@ def cim_mf_matmul_programmed(x: jax.Array, prog: ProgrammedMacro,
             "offset perturb. Re-program the projection with "
             "prefer_lossless=False (program_weights(..., "
             "prefer_lossless=False)).")
-    else:
-        parts = _lossless_partials(x2, prog.lossless, cfg, prog.sx,
-                                   prog.r_w)
-        y = cim_mf_recombine(parts, prog.sw, prog.sx, cfg)
+    return _lossless_partials(x2, prog.lossless, cfg, prog.sx, prog.r_w)
+
+
+def cim_mf_matmul_programmed(x: jax.Array, prog: ProgrammedMacro,
+                             cfg: CimConfig,
+                             cap_weights: Optional[jax.Array] = None,
+                             comparator_offset: Optional[jax.Array] = None,
+                             silicon: Optional[ProjectionSilicon] = None,
+                             silicon_kernel: Optional[CimKernelSilicon]
+                             = None) -> jax.Array:
+    """Step-time MF correlation x:(...,K) against a programmed macro
+    (:func:`programmed_partials`, then one recombine).
+
+    Bit-identical to ``cim_mf_matmul(x, w, cfg)`` whenever ``prog`` was
+    programmed with the same ``cfg`` and the dynamic activation scale of
+    ``x`` (the parity tested by tests/test_programmed.py).
+    """
+    batch_shape = x.shape[:-1]
+    x2 = x.reshape(-1, x.shape[-1])
+    parts = programmed_partials(x2, prog, cfg, cap_weights,
+                                comparator_offset, silicon, silicon_kernel)
+    y = cim_mf_recombine(parts, prog.sw, prog.sx, cfg)
     return y.reshape(batch_shape + (prog.n_out,)).astype(x.dtype)
 
 
@@ -489,20 +503,18 @@ def swap_macro(w: jax.Array, cfg: CimConfig, tile_slots: int, *,
     return SwappedMacro(sw, sx, sched)
 
 
-def cim_mf_matmul_swapped(x: jax.Array, w: jax.Array, swap: SwappedMacro,
-                          cfg: CimConfig,
-                          silicon: Optional[ProjectionSilicon] = None
-                          ) -> jax.Array:
-    """Round-interleaved MF correlation x:(...,K) against a swap-scheduled
+def cim_swapped_partials(x2: jax.Array, w: jax.Array, swap: SwappedMacro,
+                         cfg: CimConfig,
+                         silicon: Optional[ProjectionSilicon] = None
+                         ) -> CimPartials:
+    """Round-interleaved µArray pass of x2:(B, K) against a swap-scheduled
     projection: program round r's tiles (weight-side work, per STREAM — the
     reprogram events billed by the compiler's Eq. 4 roll-up), stream the
-    step-time inputs through them, swap in round r+1.
+    step-time inputs through them, swap in round r+1. Returns the
+    integer-valued code sums; :func:`cim_mf_matmul_swapped` recombines.
 
-    Bit-identical to ``cim_mf_matmul_programmed`` against a resident macro
-    programmed with the same ``sw``/``sx``: partial code sums are
-    integer-valued floats, so per-segment ``.at[].add`` accumulation is
-    exact regardless of the round partition, and the single final
-    recombine applies the same rounding sequence.
+    Partial code sums are integer-valued floats, so per-segment
+    ``.at[].add`` accumulation is exact regardless of the round partition.
 
     ``silicon`` carries the per-TILE ADC instances of the projection: the
     swap rounds fill fleet slots 0..S-1 in tile order, and the silicon
@@ -515,8 +527,6 @@ def cim_mf_matmul_swapped(x: jax.Array, w: jax.Array, swap: SwappedMacro,
     if w.shape != (K, N):
         raise ValueError(f"swap schedule is for ({K}, {N}), weight is "
                          f"{w.shape}")
-    batch_shape = x.shape[:-1]
-    x2 = x.reshape(-1, K)
     b = x2.shape[0]
     s1 = jnp.zeros((b, N), jnp.float32)
     s2 = jnp.zeros((b, N), jnp.float32)
@@ -532,9 +542,39 @@ def cim_mf_matmul_swapped(x: jax.Array, w: jax.Array, swap: SwappedMacro,
             s2 = s2.at[:, n0:n1].add(p.s2c)
             r_w = r_w.at[:, n0:n1].add(p.r_w)
     rxc = cim_rx_partials(x2, cfg, swap.sx, silicon)
-    y = cim_mf_recombine(CimPartials(s1, s2, rxc, r_w), swap.sw, swap.sx,
-                         cfg)
-    return y.reshape(batch_shape + (N,)).astype(x.dtype)
+    return CimPartials(s1, s2, rxc, r_w)
+
+
+def cim_mf_matmul_swapped(x: jax.Array, w: jax.Array, swap: SwappedMacro,
+                          cfg: CimConfig,
+                          silicon: Optional[ProjectionSilicon] = None
+                          ) -> jax.Array:
+    """Round-interleaved MF correlation x:(...,K) against a swap-scheduled
+    projection (:func:`cim_swapped_partials`, then one recombine).
+
+    Bit-identical to ``cim_mf_matmul_programmed`` against a resident macro
+    programmed with the same ``sw``/``sx``: the integer code sums are
+    exact, and the single final recombine applies the same rounding
+    sequence.
+    """
+    batch_shape = x.shape[:-1]
+    x2 = x.reshape(-1, swap.sched.k)
+    parts = cim_swapped_partials(x2, w, swap, cfg, silicon)
+    y = cim_mf_recombine(parts, swap.sw, swap.sx, cfg)
+    return y.reshape(batch_shape + (swap.sched.n,)).astype(x.dtype)
+
+
+def projection_partials(x2: jax.Array, node: dict,
+                        cfg: CimConfig) -> CimPartials:
+    """Integer code sums of one programmed projection node (``"prog"``,
+    plus ``"w"``/``"sil"``/``"silk"`` where present) on x2:(B, K): the
+    pre-recombine statistics every route must agree on bit for bit."""
+    prog = node["prog"]
+    if isinstance(prog, SwappedMacro):
+        return cim_swapped_partials(x2, node["w"], prog, cfg,
+                                    node.get("sil"))
+    return programmed_partials(x2, prog, cfg, silicon=node.get("sil"),
+                               silicon_kernel=node.get("silk"))
 
 
 # ---------------------------------------------------------------------------
@@ -630,12 +670,17 @@ def conv_weight_matrix(w: jax.Array) -> jax.Array:
 # Whole-model programming (the serve-time entry point).
 # ---------------------------------------------------------------------------
 
+@partial(jax.jit, static_argnames=("cfg", "prefer_lossless"))
 def _program_nd(w: jax.Array, cfg: CimConfig, sx: jax.Array,
                 prefer_lossless: bool = True) -> ProgrammedMacro:
     """Program a (..., K, N) weight, vmapping over stacked leading axes
     (scan periods, experts) so programmed leaves slice exactly like the
     parameter leaves they shadow; ``sx`` carries one scale per stacked
-    instance (shape = the leading axes)."""
+    instance (shape = the leading axes).
+
+    Compiled as one program: run op by op, the int32 bit-plane and
+    packing intermediates of a stacked full-width projection take
+    several GB of device memory at once."""
     if w.ndim == 2:
         return program_macro(w, cfg, sx=sx, prefer_lossless=prefer_lossless)
     return jax.vmap(lambda wi, si: _program_nd(wi, cfg, si,

@@ -56,17 +56,18 @@ def fake_quant(v: jax.Array, bits: int, axis: Optional[int] = None) -> jax.Array
     return v + jax.lax.stop_gradient(q - v)
 
 
-def bitplanes(mag: jax.Array, bits: int) -> jax.Array:
+def bitplanes(mag: jax.Array, bits: int, dtype=jnp.float32) -> jax.Array:
     """Decompose non-negative integer magnitudes into bitplanes.
 
-    mag: (...,) int32 in [0, 2^(bits-1)-1] -> (bits-1, ...) float32 planes,
-    plane p holding bit p (LSB first). The hardware stores |w| as
-    (bits-1) magnitude rows in a µArray (the sign occupies its own row).
+    mag: (...,) int32 in [0, 2^(bits-1)-1] -> (bits-1, ...) {0,1} planes
+    of ``dtype``, plane p holding bit p (LSB first). The hardware stores
+    |w| as (bits-1) magnitude rows in a µArray (the sign occupies its own
+    row).
     """
     nplanes = bits - 1
     shifts = jnp.arange(nplanes, dtype=jnp.int32)
     planes = (mag[None, ...] >> shifts.reshape((nplanes,) + (1,) * mag.ndim)) & 1
-    return planes.astype(jnp.float32)
+    return planes.astype(dtype)
 
 
 def from_bitplanes(planes: jax.Array) -> jax.Array:

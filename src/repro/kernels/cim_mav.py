@@ -8,6 +8,9 @@ weighted SA-ADC *code sum*
     S[b, n] = sum_p 2^p * sum_chunks ADC_code( (1/M) * sum_{j in chunk}
                                                G[b, j] * P[p, j, n] )
 
+The other side of the operator streams the planes instead: bit planes of
+|x| (Px x B x K) as the gates against the static step(w) gates (1 x K x N).
+
 i.e. the integer-valued ``CimPartials`` field of Eq. 2 (the m/levels
 rescale is applied ONCE by ``core.cim.cim_mf_recombine``, never inside the
 kernel — the same contract as the einsum paths, which is what makes the
@@ -56,7 +59,7 @@ def _cim_mav_kernel(g_ref, p_ref, o_ref, acc_ref, *, m_columns: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    g = g_ref[...]            # (bb, 128) gates for 4 chunks
+    g = g_ref[0]              # (bb, 128) gates for 4 chunks
     p = p_ref[0]              # (128, bn) bitplane for 4 chunks
     scale = jnp.exp2(plane.astype(jnp.float32))
     inv_m = 1.0 / m_columns
@@ -80,30 +83,37 @@ def _cim_mav_kernel(g_ref, p_ref, o_ref, acc_ref, *, m_columns: int,
 def cim_mav_pallas(gates: jax.Array, planes: jax.Array, *, m_columns: int,
                    adc_bits: int, bb: int = 8, bn: int = 128,
                    interpret: bool = False) -> jax.Array:
-    """gates: (B, K_pad) in {0,1}; planes: (Pw, K_pad, N) in {0,1}.
+    """gates: (Pg, B, K_pad) in {0,1}; planes: (Pp, K_pad, N) in {0,1}.
 
-    K_pad must be a multiple of 128 with chunk layout described above
-    (`ops.cim_mav` builds it). Returns (B, N) f32 plane-weighted integer
-    ADC code sums (a ``CimPartials`` field — recombine with
-    ``core.cim.cim_mf_recombine``).
+    Exactly one of Pg/Pp may exceed 1: the bit-serial side carries the
+    planes, the other operand is plane-static and broadcasts. Either way
+    the batch rows stay the second-minor axis of the gates and the output,
+    so the streamed input never needs a transposed layout. K_pad must be a
+    multiple of 128 with the chunk layout described above (`ops.cim_mav`
+    builds it). Returns (B, N) f32 plane-weighted integer ADC code sums (a
+    ``CimPartials`` field — recombine with ``core.cim.cim_mf_recombine``).
     """
-    b, k_pad = gates.shape
-    n_planes, k2, n = planes.shape
+    gp, b, k_pad = gates.shape
+    pp, k2, n = planes.shape
     assert k_pad == k2 and k_pad % (CHUNK_PAD * CHUNKS_PER_TILE) == 0
+    assert gp == 1 or pp == 1, (gates.shape, planes.shape)
     assert b % bb == 0 and n % bn == 0, (gates.shape, planes.shape, (bb, bn))
+    n_planes = max(gp, pp)
     c_steps = k_pad // (CHUNK_PAD * CHUNKS_PER_TILE)
     grid = (b // bb, n // bn, n_planes, c_steps)
     kernel = functools.partial(
         _cim_mav_kernel, m_columns=m_columns,
         adc_levels=2 ** adc_bits - 1, n_planes=n_planes, c_steps=c_steps)
+    gsel = (lambda p: p) if gp > 1 else (lambda p: 0)
+    psel = (lambda p: p) if pp > 1 else (lambda p: 0)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bb, CHUNK_PAD * CHUNKS_PER_TILE),
-                         lambda i, j, p, c: (i, c)),
+            pl.BlockSpec((1, bb, CHUNK_PAD * CHUNKS_PER_TILE),
+                         lambda i, j, p, c: (gsel(p), i, c)),
             pl.BlockSpec((1, CHUNK_PAD * CHUNKS_PER_TILE, bn),
-                         lambda i, j, p, c: (p, c, j)),
+                         lambda i, j, p, c: (psel(p), c, j)),
         ],
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, p, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
@@ -140,10 +150,13 @@ def _cim_mav_sil_kernel(*refs, adc_levels: int, n_planes: int, c_steps: int,
     for s in range(CHUNKS_PER_TILE):
         gs = g[:, s * CHUNK_PAD:(s + 1) * CHUNK_PAD]
         ps = p[s * CHUNK_PAD:(s + 1) * CHUNK_PAD, :]
+        # Cap-folded planes sit on the 2^-14 grid, off bf16's: contract
+        # at full f32 precision so the numerator stays exact on the MXU.
         # exact-ok: {0,1} gate x plane-bit/grid-cap operands — exact in f32
-        num = jnp.dot(gs, ps, preferred_element_type=jnp.float32)
-        mav = num / den_ref[s:s + 1, :]
-        off = off_ref[s:s + 1, :]
+        num = jnp.dot(gs, ps, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
+        mav = num / den_ref[0, s:s + 1, :]
+        off = off_ref[0, s:s + 1, :]
         if d_ref is not None:
             off = off + d_ref[0, s]
         v = mav + off
@@ -191,10 +204,14 @@ def cim_mav_sil_pallas(gates: jax.Array, planes: jax.Array, den: jax.Array,
                      lambda i, j, p, c: (gsel(p), i, c)),
         pl.BlockSpec((1, CHUNK_PAD * CHUNKS_PER_TILE, bn),
                      lambda i, j, p, c: (psel(p), c, j)),
-        pl.BlockSpec((CHUNKS_PER_TILE, bn), lambda i, j, p, c: (c, j)),
-        pl.BlockSpec((CHUNKS_PER_TILE, bn), lambda i, j, p, c: (c, j)),
+        # den/off ride as (c_steps, 4, N): a (1, 4, bn) block spans the
+        # whole of the second-minor axis, which Mosaic's (8, 128) tiling
+        # rule requires of a 4-row block.
+        pl.BlockSpec((1, CHUNKS_PER_TILE, bn), lambda i, j, p, c: (c, 0, j)),
+        pl.BlockSpec((1, CHUNKS_PER_TILE, bn), lambda i, j, p, c: (c, 0, j)),
     ]
-    operands = [gates, planes, den, off]
+    operands = [gates, planes, den.reshape(c_steps, CHUNKS_PER_TILE, n),
+                off.reshape(c_steps, CHUNKS_PER_TILE, n)]
     if dither is not None:
         assert dither.shape == (n_planes, c_tiles, b, n), dither.shape
         in_specs.append(pl.BlockSpec((1, CHUNKS_PER_TILE, bb, bn),

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handles shape padding/unpadding, batch-dim flattening, block-size
-selection, and the CPU fallback (interpret mode) so models can call these
-unconditionally. On CPU hosts (tests, this container) the kernels run in
-interpret mode; on TPU they compile to Mosaic.
+Handles shape padding/unpadding, batch-dim flattening and block-size
+selection so models can call these unconditionally. On the CPU backend
+(tests) the kernels run in Pallas interpret mode; on TPU they compile to
+Mosaic; any other backend raises.
 """
 # repro-lint: module=exactness-critical
 
@@ -18,8 +18,17 @@ from repro.kernels.cim_mav import (CHUNK_PAD, CHUNKS_PER_TILE,
 from repro.kernels.mf_matmul import mf_matmul_pallas
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def interpret_mode() -> bool:
+    """True on the CPU backend (Pallas interpreter), False on TPU (Mosaic).
+
+    Any other backend has no lowering for these kernels: it raises rather
+    than silently interpreting on an accelerator."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas CIM kernels run on 'tpu' (Mosaic) or 'cpu' "
+            f"(interpret mode); the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _round_up(v: int, m: int) -> int:
@@ -51,7 +60,7 @@ def mf_matmul(x: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
     xpad = jnp.pad(x2, ((0, mp - m), (0, kp - k)))
     wpad = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
     y = mf_matmul_pallas(xpad, wpad, bm=bm, bn=bn, bk=bk,
-                         interpret=_on_cpu())
+                         interpret=interpret_mode())
     return y[:m, :n].reshape(batch_shape + (n,))
 
 
@@ -108,22 +117,25 @@ def pack_planes(planes: jax.Array, m_columns: int) -> jax.Array:
 
 def cim_mav_packed(gates: jax.Array, planes: jax.Array, *, m_columns: int,
                    adc_bits: int, bb: int = 8, bn: int = 128) -> jax.Array:
-    """Digitised step-side partial sum over PRE-PACKED operands.
+    """Digitised step-side partial sum over PRE-PACKED operands -> (B, N).
 
-    gates: (B, Kp) from :func:`pack_chunks`; planes: (P, Kp, N) from
-    :func:`pack_planes`. Only B/N padding happens per call — the chunk
-    layout is assumed final, which is what lets programmed (weight-
-    stationary) state skip the per-step re-pack entirely.
+    gates: (B, Kp) or (Pg, B, Kp) from :func:`pack_chunks`; planes: (Pp,
+    Kp, N) from :func:`pack_planes`, with Pg or Pp equal to 1. Only B/N
+    padding happens per call — the chunk layout is assumed final, which is
+    what lets programmed (weight-stationary) state skip the per-step
+    re-pack entirely.
     """
-    b = gates.shape[0]
+    if gates.ndim == 2:
+        gates = gates[None]
+    b = gates.shape[1]
     n = planes.shape[-1]
     bb = _pick_block(b, bb, 8)
     bn = _pick_block(n, bn, 128)
     bp, npad = _round_up(b, bb), _round_up(n, bn)
-    g = jnp.pad(gates, ((0, bp - b), (0, 0)))
+    g = jnp.pad(gates, ((0, 0), (0, bp - b), (0, 0)))
     p = jnp.pad(planes, ((0, 0), (0, 0), (0, npad - n)))
     y = cim_mav_pallas(g, p, m_columns=m_columns, adc_bits=adc_bits,
-                       bb=bb, bn=bn, interpret=_on_cpu())
+                       bb=bb, bn=bn, interpret=interpret_mode())
     return y[:b, :n]
 
 
@@ -164,5 +176,5 @@ def cim_mav_silicon(gates: jax.Array, planes: jax.Array, den: jax.Array,
     dt = None if dither is None else jnp.pad(
         dither, ((0, 0), (0, 0), (0, bp - b), (0, npad - n)))
     y = cim_mav_sil_pallas(g, p, d, o, dt, adc_bits=adc_bits, bb=bb, bn=bn,
-                           interpret=_on_cpu())
+                           interpret=interpret_mode())
     return y[:b, :n]

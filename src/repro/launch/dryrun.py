@@ -30,6 +30,11 @@ from repro.parallel import sharding as shd
 from repro.roofline import analysis as roofline
 from repro.train import train_loop as TL
 
+# The chip the dry-run projects its compiled cells onto: the programs are
+# compiled for forced host devices, and their roofline terms are read
+# against this kind's published peaks (repro.roofline.analysis.PEAKS).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def dryrun_parallel_config(arch: str, shape: ShapeConfig, multi_pod: bool,
                            overrides: dict | None = None) -> ParallelConfig:
@@ -157,7 +162,8 @@ def _measure_variant(arch: str, shape: ShapeConfig, multi_pod: bool,
     with mesh:
         compiled = jax.jit(fn, in_shardings=in_sh,
                            donate_argnums=donate).lower(*args).compile()
-        terms = roofline.terms_from_compiled(compiled, mesh.devices.size)
+        terms = roofline.terms_from_compiled(compiled, mesh.devices.size,
+                                             TARGET_DEVICE_KIND)
     return {"flops": terms.flops, "hbm_bytes": terms.hbm_bytes,
             "coll_bytes": terms.coll_bytes,
             "coll_breakdown": terms.coll_breakdown}
@@ -189,7 +195,7 @@ def extrapolated_terms(arch: str, shape: ShapeConfig, multi_pod: bool,
         flops=ex(f1["flops"], f2["flops"]),
         hbm_bytes=ex(f1["hbm_bytes"], f2["hbm_bytes"]),
         coll_bytes=float(sum(coll.values())), chips=chips,
-        coll_breakdown=coll)
+        device_kind=TARGET_DEVICE_KIND, coll_breakdown=coll)
     return terms, {"unit1": f1, "unit2": f2, "units": units,
                    "u1": u1, "u2": u2}
 
@@ -224,7 +230,8 @@ def run_cell(arch: str, shape: ShapeConfig, multi_pod: bool, out_dir: str,
                 } if ma is not None else None
             except Exception as e:  # noqa: BLE001
                 record["memory_analysis"] = f"unavailable: {e!r}"
-            raw_terms = roofline.terms_from_compiled(compiled, chips)
+            raw_terms = roofline.terms_from_compiled(compiled, chips,
+                                                     TARGET_DEVICE_KIND)
             record["roofline_raw"] = raw_terms.as_dict()
         # Scan-corrected costs: extrapolate from two unrolled shallow
         # variants (XLA cost_analysis counts a loop body once).

@@ -13,56 +13,13 @@ from __future__ import annotations
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.5 exposes ``jax.shard_map`` (with the replication check
-    spelled ``check_vma``); on 0.4.x the public symbol raises
-    AttributeError through the deprecation shim and the implementation
-    lives at ``jax.experimental.shard_map.shard_map`` with the check
-    spelled ``check_rep``. Model code calls this wrapper so both runtimes
-    lower the same programs.
-    """
-    sm = getattr(jax, "shard_map", None)
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if sm is not None:
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return sm(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as sm_old
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return sm_old(f, **kwargs)
-
-
-def axis_size(name) -> int:
-    """Static size of a bound mesh axis, inside ``shard_map``.
-
-    ``jax.lax.axis_size`` only exists from jax 0.5; on 0.4.x the axis
-    environment exposes the same static int via ``jax.core.axis_frame``.
-    """
-    sz = getattr(jax.lax, "axis_size", None)
-    if sz is not None:
-        return sz(name)
-    return jax.core.axis_frame(name)
-
-
-def auto_axis_types(n_axes: int) -> dict:
-    """``axis_types`` kwargs for ``jax.make_mesh``, if this jax has them.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5; on older runtimes
-    every mesh axis is implicitly Auto, so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
 
 
 def make_local_mesh(model_axis: int = 1, data_axis: int = 1):
@@ -70,7 +27,7 @@ def make_local_mesh(model_axis: int = 1, data_axis: int = 1):
     n = len(jax.devices())
     data_axis = max(1, min(data_axis, n // model_axis))
     return jax.make_mesh((data_axis, model_axis), ("data", "model"),
-                         **auto_axis_types(2))
+                         axis_types=(AUTO,) * 2)
 
 
 def make_serve_mesh(data: int = 0, fleet: int = 1, devices=None):
@@ -83,15 +40,15 @@ def make_serve_mesh(data: int = 0, fleet: int = 1, devices=None):
     bank). ``data=0`` takes every device not consumed by ``fleet``.
     ``devices`` restricts the mesh to an explicit device list (e.g. the
     single-device parity mesh) — built through ``jax.sharding.Mesh``
-    directly because ``jax.make_mesh`` on this jax picks from the global
-    device set only.
+    directly because ``jax.make_mesh`` always lays its mesh out over the
+    global device set.
     """
     if devices is None:
         n = len(jax.devices())
         if data <= 0:
             data = max(1, n // fleet)
         return jax.make_mesh((data, fleet), ("data", "fleet"),
-                             **auto_axis_types(2))
+                             axis_types=(AUTO,) * 2)
     import numpy as np
     devs = np.asarray(devices, dtype=object)
     if data <= 0:

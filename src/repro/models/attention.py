@@ -290,8 +290,7 @@ def flash_decode_sharded(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         out = og / jnp.maximum(lg, 1e-30)[..., None]
         return out.astype(qL.dtype), kc2, vc2
 
-    from repro.launch.mesh import shard_map
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None), P(dp, None, None, None),
                   P(dp, None, None, None), P(dp, tp, None, None),

@@ -170,9 +170,8 @@ def moe_apply_ep(p: dict, x: jax.Array, *, top_k: int, ep_axis: str,
     s, d = x.shape
     axes = (ep_axis,) if isinstance(ep_axis, str) else tuple(ep_axis)
     n_ep = 1
-    from repro.launch.mesh import axis_size
     for a in axes:                        # static: reads the axis env
-        n_ep *= axis_size(a)
+        n_ep *= jax.lax.axis_size(a)
     e_local = p["experts"]["up"].shape[0]
     n_experts = p["router"]["w"].shape[-1]
     assert n_experts == e_local * n_ep, (n_experts, e_local, n_ep)

@@ -175,8 +175,7 @@ def _moe_apply(p: dict, x: jax.Array, cfg: ModelConfig, pctx: ParallelContext,
               "experts": expert_specs}
     if "shared" in p:
         pspecs["shared"] = jax.tree.map(lambda _: P(), p["shared"])
-    from repro.launch.mesh import shard_map
-    return shard_map(
+    return jax.shard_map(
         ep_fn, mesh=pctx.mesh,
         in_specs=(pspecs, x_spec),
         out_specs=(out_spec, P()),

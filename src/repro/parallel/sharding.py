@@ -315,8 +315,7 @@ def exec_param_pspecs(params, axis_sizes: dict, fleet_axis: str = "fleet"):
             state=None if pm.state is None else CimPackedPlanes(
                 packed=last_dim(pm.state.packed),
                 r_w=last_dim(pm.state.r_w)),
-            # Kernel state keeps mixed layouts ((N, Kp) gates vs
-            # (Pw, Kp, N) planes) — replicated; the Pallas path is not a
+            # Kernel state replicates: the Pallas path is not a
             # traffic-lab target.
             kernel=None if pm.kernel is None else rep(pm.kernel),
             lossless=None if pm.lossless is None else CimLosslessState(

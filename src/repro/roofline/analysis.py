@@ -13,8 +13,9 @@ all-reduce / reduce-scatter / all-to-all / collective-permute (result size
 == wire payload for all-reduce/permute; for all-gather it upper-bounds the
 per-device payload by the gathered size — documented approximation).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment).
+Hardware peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``
+of the chip the terms describe; a kind missing from the table is an error,
+never a default.
 """
 
 from __future__ import annotations
@@ -23,9 +24,35 @@ import dataclasses
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops: float        # bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    link_bw: float      # bytes/s per inter-chip link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.
+PEAKS: dict[str, ChipPeaks] = {
+    # 1,600 Gbit/s of chip-to-chip interconnect over 4 ICI links.
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=1600e9 / 8 / 4,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The :data:`PEAKS` entry of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}) — add them, with their source, to "
+            f"repro.roofline.analysis.PEAKS") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -81,19 +108,23 @@ class RooflineTerms:
     hbm_bytes: float              # per-device bytes accessed
     coll_bytes: float             # per-device collective payload bytes
     chips: int
+    device_kind: str              # the chip kind whose peaks apply
     coll_breakdown: Optional[dict] = None
+
+    def __post_init__(self):
+        self.peaks = peaks_for(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / LINK_BW
+        return self.coll_bytes / self.peaks.link_bw
 
     @property
     def dominant(self) -> str:
@@ -109,13 +140,15 @@ class RooflineTerms:
         return {
             "flops": self.flops, "hbm_bytes": self.hbm_bytes,
             "coll_bytes": self.coll_bytes, "chips": self.chips,
+            "device_kind": self.device_kind,
             "compute_s": self.compute_s, "memory_s": self.memory_s,
             "collective_s": self.collective_s, "dominant": self.dominant,
             "coll_breakdown": self.coll_breakdown,
         }
 
 
-def terms_from_compiled(compiled, chips: int) -> RooflineTerms:
+def terms_from_compiled(compiled, chips: int,
+                        device_kind: str) -> RooflineTerms:
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0]
@@ -128,7 +161,7 @@ def terms_from_compiled(compiled, chips: int) -> RooflineTerms:
     coll = collective_bytes(hlo)
     return RooflineTerms(flops=flops, hbm_bytes=hbm,
                          coll_bytes=float(sum(coll.values())), chips=chips,
-                         coll_breakdown=coll)
+                         device_kind=device_kind, coll_breakdown=coll)
 
 
 # ---------------------------------------------------------------------------

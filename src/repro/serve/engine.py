@@ -453,9 +453,6 @@ class ServeEngine:
     def _compile_fleet_schedule(self):
         """Compile the model's projections onto the fleet; returns the
         ``program_weights`` swap map (None when the model pins)."""
-        from repro.compiler.frontend import projection_layer_stats
-        from repro.compiler.schedule import compile_model
-        from repro.core.mapping import MappingPolicy
         fleet, cim = self.fleet, self.cfg.mf.cim
         if (fleet.cfg.m_columns, fleet.cfg.w_bits) != (cim.m_columns,
                                                        cim.w_bits):
@@ -463,13 +460,8 @@ class ServeEngine:
                 f"fleet µArray geometry (M={fleet.cfg.m_columns}, "
                 f"W_P={fleet.cfg.w_bits}) does not match the model's "
                 f"CimConfig (M={cim.m_columns}, W_P={cim.w_bits})")
-        stats, groups = projection_layer_stats(self.params,
-                                               calls=self.slots)
-        # Every walked projection executes in cim_sim here, so the policy
-        # gate is wide open — the fleet decides residency, not ops/param.
-        self.schedule = compile_model(
-            stats, fleet, policy=MappingPolicy(threshold=0.0,
-                                               always_digital=()))
+        self.schedule, groups = fleet_schedule(self.params, fleet,
+                                               self.slots)
         # The schedule is frozen for the engine's lifetime: roll up its
         # Eq. 4 utilization once instead of per run().
         from repro.compiler.cost import model_cost
@@ -641,7 +633,7 @@ class ServeEngine:
                                                    sub, step_idx)
         if self._sanitizer is not None:
             self._sanitizer.check_step(self, cache_before, tokens, sub,
-                                       step_idx, nxt, logits)
+                                       step_idx, logits)
         self._c_decode_steps.inc()
         nxt = np.asarray(nxt)
         for s, req in enumerate(self.requests):
@@ -915,6 +907,20 @@ class ServeEngine:
             recal_reload_bits=recal_reload_bits, recal_energy_j=recal_j,
             recal_s=recal_s, retired_slots=retired_slots,
             evicted_tokens=evicted_tokens)
+
+
+def fleet_schedule(params, fleet, calls: int):
+    """Compile a model's projections onto ``fleet``: the schedule a
+    :class:`ServeEngine` with ``slots=calls`` serves from. Returns
+    ``(ModelSchedule, projection groups)``."""
+    from repro.compiler.frontend import projection_layer_stats
+    from repro.compiler.schedule import compile_model
+    from repro.core.mapping import MappingPolicy
+    stats, groups = projection_layer_stats(params, calls=calls)
+    # Every walked projection executes in cim_sim here, so the policy
+    # gate is wide open — the fleet decides residency, not ops/param.
+    return compile_model(stats, fleet, policy=MappingPolicy(
+        threshold=0.0, always_digital=())), groups
 
 
 def _check_calibration_names(params, calibration) -> None:

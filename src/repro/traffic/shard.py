@@ -64,6 +64,19 @@ def shard_engine(engine, mesh) -> dict:
     engine._exec_params = jax.device_put(engine._exec_params, param_sh)
     engine.mesh = mesh
 
+    def under_mesh(fn):
+        """``fn`` traced and run with ``mesh`` as JAX's current mesh, which
+        is what makes programmed projections gather their fleet-sharded
+        outputs (``repro.core.mf.apply_projection``)."""
+        def call(*args):
+            with jax.set_mesh(mesh):
+                return fn(*args)
+        return call
+
+    engine.step_fn = under_mesh(engine.step_fn)
+    engine._traced_step_fn = under_mesh(engine._traced_step_fn)
+    engine._prefill_fn = under_mesh(engine._prefill_fn)
+
     def _reput(eng):
         """Exec-refresh hook: a re-attached/re-programmed tree is born on
         the default device — put it back on the mesh. The tree STRUCTURE

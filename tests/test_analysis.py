@@ -503,13 +503,14 @@ class TestSanitizerEngine:
         from repro.analysis.sanitize import SanitizeError
         from repro.core import programmed as P
         from repro.serve.engine import Request
-        orig = P.cim_kernel_forward
+        orig = P.cim_kernel_partials
 
-        def corrupted(x2, ks, cfg, sw, sx, dac_gains=None):
+        def corrupted(x2, ks, cfg, sx, dac_gains=None):
             # one ADC-code quantum of divergence on the fused path only
-            return orig(x2, ks, cfg, sw, sx, dac_gains) + 1e-3
+            parts = orig(x2, ks, cfg, sx, dac_gains)
+            return parts._replace(s1c=parts.s1c.at[0, 0].add(1.0))
 
-        monkeypatch.setattr(P, "cim_kernel_forward", corrupted)
+        monkeypatch.setattr(P, "cim_kernel_partials", corrupted)
         eng = _kernel_engine(monkeypatch)
         with pytest.raises(SanitizeError, match="fused/einsum divergence"):
             eng.run([Request(prompt=[1, 2], max_new_tokens=2)])

@@ -71,3 +71,16 @@ class TestCimMavKernel:
         planes2 = jnp.pad(planes, ((0, 0), (0, 10), (0, 0)))
         y2 = ops.cim_mav(gates2, planes2, m_columns=31, adc_bits=5)
         np.testing.assert_allclose(y1, y2)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_per_backend(monkeypatch, backend, interpret):
+    """CPU interprets, TPU compiles to Mosaic, anything else raises rather
+    than interpreting on an accelerator."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret

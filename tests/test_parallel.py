@@ -94,8 +94,8 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
                                     MFTechniqueConfig)
     from repro.models import transformer as T
 
-    from repro.launch.mesh import auto_axis_types
-    mesh = jax.make_mesh((2, 4), ("data", "model"), **auto_axis_types(2))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = ModelConfig(name="t", family="moe", n_layers=2, d_model=32,
                       n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=64,
                       moe=MoEConfig(n_experts=8, top_k=2, n_shared=1,
@@ -140,8 +140,12 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
 def test_ep_moe_multidevice_subprocess():
     """EP MoE == dense MoE on a real 2x4 device mesh (subprocess so the
     fake device count doesn't leak into this test session)."""
+    # Pinned to the CPU backend: the forced host devices are the point,
+    # and the child must never contend for an accelerator this session
+    # may hold.
     r = subprocess.run([sys.executable, "-c", MULTIDEV_SCRIPT],
                        capture_output=True, text=True, timeout=540,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
                        cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))
     assert "MULTIDEV_OK" in r.stdout, r.stdout + r.stderr

@@ -7,6 +7,7 @@ except ImportError:  # deterministic fallback, see tests/_hypothesis_stub.py
     from _hypothesis_stub import hypothesis, st
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.roofline import analysis as R
 
@@ -53,8 +54,10 @@ class TestCollectiveParser:
 
 class TestTerms:
     def test_dominant_and_seconds(self):
-        t = R.RooflineTerms(flops=R.PEAK_FLOPS, hbm_bytes=R.HBM_BW * 2,
-                            coll_bytes=R.LINK_BW * 0.5, chips=1)
+        pk = R.peaks_for("TPU v5 lite")
+        t = R.RooflineTerms(flops=pk.flops, hbm_bytes=pk.hbm_bw * 2,
+                            coll_bytes=pk.link_bw * 0.5, chips=1,
+                            device_kind="TPU v5 lite")
         assert t.compute_s == 1.0
         assert t.memory_s == 2.0
         assert t.collective_s == 0.5
@@ -65,10 +68,25 @@ class TestTerms:
         f = jax.jit(lambda a, b: a @ b)
         x = jnp.ones((128, 128))
         c = f.lower(x, x).compile()
-        t = R.terms_from_compiled(c, chips=1)
+        t = R.terms_from_compiled(c, chips=1, device_kind="TPU v5 lite")
         # 2*M*N*K flops for a 128^3 matmul
         assert t.flops >= 2 * 128 ** 3 * 0.9
         assert t.coll_bytes == 0
+
+
+    def test_v5e_peaks_match_published(self):
+        pk = R.peaks_for("TPU v5 lite")
+        assert (pk.flops, pk.hbm_bw) == (197e12, 819e9)
+        assert pk.link_bw * 4 * 8 == 1600e9
+        assert "TPU v5e" in pk.source
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+    def test_unknown_device_kind_raises(self, kind):
+        with pytest.raises(ValueError, match="no published peaks"):
+            R.peaks_for(kind)
+        with pytest.raises(ValueError, match="no published peaks"):
+            R.RooflineTerms(flops=1.0, hbm_bytes=1.0, coll_bytes=0.0,
+                            chips=1, device_kind=kind)
 
 
 class TestModelFlops:

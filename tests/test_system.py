@@ -3,6 +3,7 @@ serving engine, data pipeline."""
 
 import dataclasses
 import os
+import pathlib
 import tempfile
 
 import jax
@@ -125,8 +126,8 @@ class TestFaultTolerance:
         # restore with explicit shardings — the reshard path used when the
         # mesh changes between runs (elastic scaling)
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import auto_axis_types
-        mesh = jax.make_mesh((1,), ("data",), **auto_axis_types(1))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         tree = {"w": jnp.arange(8.0).reshape(2, 4)}
         with tempfile.TemporaryDirectory() as d:
             ckpt.save(d, 3, tree)
@@ -171,3 +172,30 @@ class TestImageData:
         diff = [float(np.corrcoef(x[i].ravel(), x[j].ravel())[0, 1])
                 for i in range(16) for j in range(16) if y[i] != y[j]]
         assert np.mean(same or [1.0]) > np.mean(diff or [0.0])
+
+
+class TestCompileCache:
+    """``enable_compile_cache``: the environment's directory when set,
+    else the repo's fixed ``.jax_cache``."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_dir_is_the_only_cache(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_the_repo_dir(self, monkeypatch):
+        from repro.launch.compile_cache import (REPO_CACHE_DIR,
+                                                enable_compile_cache)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert REPO_CACHE_DIR == pathlib.Path(root) / ".jax_cache"
+        assert enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)

@@ -446,8 +446,12 @@ def test_traffic_multidevice_subprocess():
     """Sharded serving on a real 4-device host mesh is bitwise equal to
     the unsharded engine (subprocess so the fake device count doesn't
     leak into this test session)."""
+    # Pinned to the CPU backend: the forced host devices are the point,
+    # and the child must never contend for an accelerator this session
+    # may hold.
     r = subprocess.run([sys.executable, "-c", MULTIDEV_TRAFFIC_SCRIPT],
                        capture_output=True, text=True, timeout=540,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
                        cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))
     assert "MULTIDEV_TRAFFIC_OK" in r.stdout, r.stdout + r.stderr
