@@ -1,21 +1,11 @@
-"""Observability report: parity, overhead, and the drift-story gates.
+"""Observability report: the drift story, from the exported trace.
 
-Three sections over ``repro.obs`` (the fleet-telemetry layer):
-
-  * **parity** — tracing DISABLED must cost nothing: on the qwen3 smoke
-    config, pinned / swapped / sigma0-silicon engines decode tokens that
-    are BITWISE identical whether no bus is installed, a bus is
-    installed against an untraced engine (host emitters only), or the
-    engine itself was built ``tracing=True`` (in-jit ``io_callback``
-    emission) — the callback is a pure side channel, never a value.
-  * **overhead** — tracing ENABLED on the qwen3 smoke decode loop costs
-    <= 5% steady-state tokens/sec versus the untraced engine.
-  * **drift story** — a drifting silicon fleet served under a detail
-    bus; the exported JSONL trace alone (re-read from disk, not the live
-    buffer) must reconstruct the full drift-alarm -> retrim/retire ->
-    recalibration maintenance narrative, render the fleet tier heatmap,
-    and the engine's metrics must round-trip through the Prometheus
-    text exposition.
+A drifting silicon fleet served under a detail bus; the exported JSONL
+trace alone (re-read from disk, not the live buffer) must reconstruct the
+full drift-alarm -> retrim/retire -> recalibration maintenance narrative,
+render the fleet tier heatmap, and the engine's metrics must round-trip
+through the Prometheus text exposition. (Time on the chip is not measured
+here: a ``jax.profiler`` trace holds the program's spans and scopes.)
 
 Emits ``BENCH_obs.json`` plus the sample trace ``BENCH_obs_trace.jsonl``
 (both CI artifacts) and the ``benchmarks/run.py`` CSV rows.
@@ -30,7 +20,6 @@ import argparse
 import dataclasses
 import json
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +27,6 @@ import jax.numpy as jnp
 from repro import obs
 from repro.compiler.tiling import Fleet
 from repro.configs.base import MFTechniqueConfig, ModelConfig
-from repro.configs.qwen3_0_6b import SMOKE
 from repro.core.cim import CimConfig
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine
@@ -47,127 +35,8 @@ OUT_PATH = os.environ.get("BENCH_OBS_OUT", "BENCH_obs.json")
 TRACE_PATH = os.environ.get("BENCH_OBS_TRACE_OUT", "BENCH_obs_trace.jsonl")
 
 
-def _qwen_cfg():
-    """qwen3 smoke proportions, every MF projection on cim_sim."""
-    cim = CimConfig(w_bits=8, x_bits=8, adc_bits=5, m_columns=31)
-    mf = MFTechniqueConfig(mode="cim_sim", cim=cim)
-    return dataclasses.replace(SMOKE, dtype=jnp.float32, mf=mf)
-
-
-def _greedy_tokens(engine: ServeEngine, prompt: list[int], n: int,
-                   n_reqs: int) -> list[list[int]]:
-    done = engine.run([Request(prompt=list(prompt), max_new_tokens=n)
-                       for _ in range(n_reqs)])
-    return [r.out for r in done]
-
-
 # ---------------------------------------------------------------------------
-# Section 1: tracing-disabled bitwise parity (pinned / swapped / silicon).
-# ---------------------------------------------------------------------------
-
-def _parity_section(params, cfg) -> dict:
-    from repro.silicon.instance import SiliconConfig
-    cim = cfg.mf.cim
-    sigma0 = SiliconConfig(cap_sigma=0.0, comparator_sigma_v=0.0)
-    swap_fleet = Fleet(n_macros=64, cfg=cim)
-    # Size the pinned fleet off the swap schedule (same trick as
-    # serve_bench): each macro carries several tile slots, so half the
-    # tile count in macros still pins the whole model.
-    probe = ServeEngine(params, cfg, slots=2, max_len=16,
-                        fleet=swap_fleet, batched_prefill=False)
-    assert not probe.schedule.pinned and probe.schedule.rounds_max > 1
-    pin_fleet = Fleet(n_macros=-(-probe.schedule.total_tiles // 2), cfg=cim)
-
-    def build(kind: str, tracing: bool) -> ServeEngine:
-        # Interval 1: EVERY tick goes through the traced twin program,
-        # the strongest form of the parity assertion.
-        kw = dict(slots=2, max_len=16, batched_prefill=False,
-                  tracing=tracing, trace_tick_interval=1)
-        if kind == "pinned":
-            return ServeEngine(params, cfg, fleet=pin_fleet, **kw)
-        if kind == "swapped":
-            return ServeEngine(params, cfg, fleet=swap_fleet, **kw)
-        return ServeEngine(params, cfg, fleet=pin_fleet, silicon=sigma0,
-                           **kw)
-
-    out: dict = {}
-    for kind in ("pinned", "swapped", "silicon"):
-        if kind == "swapped":
-            ref_eng = probe                   # reuse the sizing probe
-        else:
-            ref_eng = build(kind, tracing=False)
-        assert obs.bus() is None
-        ref = _greedy_tokens(ref_eng, [1, 2, 3], 4, 2)   # no bus at all
-        with obs.tracing() as buf:
-            host_only = _greedy_tokens(build(kind, False), [1, 2, 3], 4, 2)
-            traced = _greedy_tokens(build(kind, True), [1, 2, 3], 4, 2)
-            ticks = len(buf.by_kind("decode_tick"))
-        assert host_only == ref, f"{kind}: bus install changed tokens"
-        assert traced == ref, f"{kind}: in-jit emission changed tokens"
-        assert ticks > 0, f"{kind}: traced engine emitted no decode_tick"
-        out[kind] = {"bitwise_identical": True, "decode_ticks": ticks,
-                     "host_events": buf.total}
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Section 2: tracing-enabled decode overhead (<= 5%).
-# ---------------------------------------------------------------------------
-
-def _overhead_section(params, cfg, quick: bool) -> dict:
-    """Steady-state decode tok/s, untraced vs tracing at the DEFAULT
-    sampling cadence. Each timed window spans several cadence periods so
-    the traced-twin dispatches it pays for are inside the measurement,
-    not between windows."""
-    import inspect
-    interval = inspect.signature(ServeEngine.__init__) \
-        .parameters["trace_tick_interval"].default
-    import numpy as np
-    periods = 2 if quick else 4
-    warmup, reps = 3, 3
-    ticks = periods * interval
-    max_len = reps * ticks + warmup + 4
-
-    def window(eng):
-        t0 = time.perf_counter()
-        for _ in range(ticks):
-            eng.step()
-        jax.block_until_ready(eng.cache["pos"])
-        return time.perf_counter() - t0
-
-    with obs.tracing():
-        plain = ServeEngine(params, cfg, slots=2, max_len=max_len)
-        traced = ServeEngine(params, cfg, slots=2, max_len=max_len,
-                             tracing=True)
-        for eng in (plain, traced):
-            for _ in range(eng.slots):
-                eng.submit(Request(prompt=[1], max_new_tokens=1 << 30))
-            for _ in range(warmup):
-                eng.step()
-        # Interleave the timed windows so host-level drift (cache
-        # warmth, frequency scaling) hits both engines alike.
-        t_plain, t_traced = [], []
-        for _ in range(reps):
-            t_plain.append(window(plain))
-            t_traced.append(window(traced))
-        plain_tok_s = plain.slots * ticks / float(np.min(t_plain))
-        traced_tok_s = traced.slots * ticks / float(np.min(t_traced))
-        n_ticks = len(obs.bus().by_kind("decode_tick"))
-    assert n_ticks >= (warmup + reps * ticks) // interval, (
-        f"traced run emitted {n_ticks} decode_ticks over "
-        f"{warmup + reps * ticks} ticks at cadence {interval}")
-    overhead = 1.0 - traced_tok_s / plain_tok_s
-    assert overhead <= 0.05, (
-        f"tracing overhead {overhead:.1%} > 5% "
-        f"({traced_tok_s:.1f} vs {plain_tok_s:.1f} tok/s)")
-    return {"untraced_tok_s": plain_tok_s, "traced_tok_s": traced_tok_s,
-            "overhead_frac": overhead, "gate_5pct": overhead <= 0.05,
-            "trace_tick_interval": interval, "ticks": ticks,
-            "reps": reps, "decode_ticks_emitted": n_ticks}
-
-
-# ---------------------------------------------------------------------------
-# Section 3: drift story + heatmap + export round-trips.
+# The drift story + heatmap + export round-trips.
 # ---------------------------------------------------------------------------
 
 def _drift_cfg():
@@ -204,8 +73,7 @@ def _drift_section() -> dict:
     with obs.tracing(detail=True) as buf:
         eng = ServeEngine(params, cfg, slots=2, max_len=48, fleet=fleet,
                           batched_prefill=False, calibration=art,
-                          silicon=scfg, drift=pol, tracing=True,
-                          trace_tick_interval=1)
+                          silicon=scfg, drift=pol)
         eng.run([Request(prompt=[1, 2, 3], max_new_tokens=12)
                  for _ in range(2)])
         prom_text = obs.to_prometheus(eng.metrics)
@@ -262,35 +130,15 @@ def _drift_section() -> dict:
 
 
 def run(quick: bool = True):
-    cfg = _qwen_cfg()
-    params = T.lm_init(jax.random.PRNGKey(0), cfg)
-
-    parity = _parity_section(params, cfg)
-    overhead = _overhead_section(params, cfg, quick)
+    """The drift story has one size; ``quick`` is the suite protocol's."""
     drift = _drift_section()
-
-    payload = {
-        "bench": "obs_report",
-        "config": cfg.name,
-        "quick": quick,
-        "parity": parity,
-        "overhead": overhead,
-        "drift": drift,
-    }
+    payload = {"bench": "obs_report", "drift": drift}
     with open(OUT_PATH, "w") as f:
         json.dump(payload, f, indent=2)
         f.write("\n")
 
     story = drift["story"]
-    rows = [(f"obs_parity_{kind}", 0.0,
-             f"bitwise={p['bitwise_identical']} "
-             f"decode_ticks={p['decode_ticks']}")
-            for kind, p in parity.items()]
-    rows += [
-        ("obs_overhead", 1e6 / overhead["traced_tok_s"],
-         f"traced={overhead['traced_tok_s']:.1f} "
-         f"untraced={overhead['untraced_tok_s']:.1f} tok/s "
-         f"overhead={overhead['overhead_frac']:.1%} gate<=5%"),
+    return [
         ("obs_drift_story", 0.0,
          f"complete={story['complete']} alarm@{story['alarm_stream']} "
          f"recal@{story['recal_stream']} retire@{story['retire_stream']} "
@@ -300,7 +148,6 @@ def run(quick: bool = True):
          f"events={drift['events_exported']} jsonl+prometheus exact "
          f"json={OUT_PATH} trace={TRACE_PATH}"),
     ]
-    return rows
 
 
 def main() -> None:
@@ -308,7 +155,7 @@ def main() -> None:
     enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="small qwen3 smoke shapes (CI)")
+                    help="accepted for CI; the drift story has one size")
     args = ap.parse_args()
     for name, us, derived in run(quick=args.smoke):
         print(f"{name},{us:.1f},{derived}")

@@ -18,8 +18,7 @@
 # TIER1_MACRO_BENCH=1 additionally runs the macro-zoo smoke (registry
 # parity, collaborative area re-budget + compiler tile shrink, MC yield
 # over macro models, tiered re-trim aging) and leaves BENCH_macros.json.
-# TIER1_OBS_BENCH=1 additionally runs the observability smoke (tracing
-# disabled = bitwise decode parity, tracing <= 5% tok/s overhead, drift
+# TIER1_OBS_BENCH=1 additionally runs the observability smoke (drift
 # alarm -> retrim/retire -> recal story reconstructed from the exported
 # trace) and leaves BENCH_obs.json + BENCH_obs_trace.jsonl.
 # TIER1_LINT=1 additionally gates on the static passes: repro-lint

@@ -24,6 +24,7 @@ is what an SRAM sign bit encodes — see ``hw_sign``.
 
 from __future__ import annotations
 
+import contextlib
 import enum
 import math
 from functools import partial
@@ -236,65 +237,71 @@ def apply_projection(params: dict, x: jax.Array, mode: ExecMode | str,
     against the float MF reference on the same input (SQNR mode).
     """
     mode = ExecMode(mode)
-    w = params["w"]
-    if _calib_tap.stats_active() and mode != ExecMode.REGULAR:
-        _calib_tap.record_activation(params.get("obs_id"), x)
-    if mode == ExecMode.REGULAR:
-        y = x @ w
-    elif mode == ExecMode.MF:
-        y = mf_matmul(x, w, delta_sigma, delta_coeff)
-    elif mode == ExecMode.MF_KERNEL:
-        from repro.kernels import ops as kops  # local import: kernels optional
-        y = kops.mf_matmul(x, w)
-    elif mode == ExecMode.CIM_SIM:
-        from repro.core import cim
-        assert cim_cfg is not None, "CIM_SIM mode requires a CimConfig"
-        prog = programmed if programmed is not None else params.get("prog")
-        if prog is not None:
-            from repro.core.programmed import (SwappedMacro,
-                                               cim_mf_matmul_programmed,
-                                               cim_mf_matmul_swapped)
-            # Per-slot silicon instances (repro.silicon.instance
-            # .attach_silicon embeds them as "sil", riding scans exactly
-            # like the programmed state they perturb).
-            sil = params.get("sil")
-            # Fence the projection: the float ops that make x and consume
-            # y then compile to the same fusions whichever route serves it
-            # (lossless collapse, plane einsum, fused kernel), so they round
-            # alike and the routes' tokens agree bitwise on a TPU too.
-            x = jax.lax.optimization_barrier(x)
-            if isinstance(prog, SwappedMacro):
-                # Fleet too small to pin this projection: round-interleaved
-                # execution re-programs tiles per input stream.
-                y = cim_mf_matmul_swapped(x, w, prog, cim_cfg, silicon=sil)
+    # Every MF projection's ops carry the ``mf_projection`` scope in the
+    # compiled program's metadata (programmed CIM routes add their own
+    # sub-scope), so a profile can total them.
+    scope = (contextlib.nullcontext() if mode == ExecMode.REGULAR
+             else jax.named_scope("mf_projection"))
+    with scope:
+        w = params["w"]
+        if _calib_tap.stats_active() and mode != ExecMode.REGULAR:
+            _calib_tap.record_activation(params.get("obs_id"), x)
+        if mode == ExecMode.REGULAR:
+            y = x @ w
+        elif mode == ExecMode.MF:
+            y = mf_matmul(x, w, delta_sigma, delta_coeff)
+        elif mode == ExecMode.MF_KERNEL:
+            from repro.kernels import ops as kops  # local import: kernels optional
+            y = kops.mf_matmul(x, w)
+        elif mode == ExecMode.CIM_SIM:
+            from repro.core import cim
+            assert cim_cfg is not None, "CIM_SIM mode requires a CimConfig"
+            prog = programmed if programmed is not None else params.get("prog")
+            if prog is not None:
+                from repro.core.programmed import (SwappedMacro,
+                                                   cim_mf_matmul_programmed,
+                                                   cim_mf_matmul_swapped)
+                # Per-slot silicon instances (repro.silicon.instance
+                # .attach_silicon embeds them as "sil", riding scans exactly
+                # like the programmed state they perturb).
+                sil = params.get("sil")
+                # Fence the projection: the float ops that make x and consume
+                # y then compile to the same fusions whichever route serves it
+                # (lossless collapse, plane einsum, fused kernel), so they round
+                # alike and the routes' tokens agree bitwise on a TPU too.
+                x = jax.lax.optimization_barrier(x)
+                if isinstance(prog, SwappedMacro):
+                    # Fleet too small to pin this projection: round-interleaved
+                    # execution re-programs tiles per input stream.
+                    y = cim_mf_matmul_swapped(x, w, prog, cim_cfg, silicon=sil)
+                else:
+                    y = cim_mf_matmul_programmed(x, prog, cim_cfg, silicon=sil,
+                                                 silicon_kernel=params.get(
+                                                     "silk"))
+                from repro.analysis import sanitize
+                if sanitize.SANITIZE_REF in params:
+                    # REPRO_SANITIZE's paired replay step only.
+                    sanitize.check_partials(x, params, cim_cfg)
+                y = jax.lax.optimization_barrier(y)
+                if "fleet" in jax.sharding.get_abstract_mesh().axis_names:
+                    # Fleet-sharded serving (repro.traffic.shard): each device
+                    # holds a slice of the output channels. Gather them, so the
+                    # float model downstream reduces whole rows in the same
+                    # order as on one device.
+                    P = jax.sharding.PartitionSpec
+                    y = jax.lax.with_sharding_constraint(
+                        y, P(*[P.UNCONSTRAINED] * (y.ndim - 1), None))
             else:
-                y = cim_mf_matmul_programmed(x, prog, cim_cfg, silicon=sil,
-                                             silicon_kernel=params.get(
-                                                 "silk"))
-            from repro.analysis import sanitize
-            if sanitize.SANITIZE_REF in params:
-                # REPRO_SANITIZE's paired replay step only.
-                sanitize.check_partials(x, params, cim_cfg)
-            y = jax.lax.optimization_barrier(y)
-            if "fleet" in jax.sharding.get_abstract_mesh().axis_names:
-                # Fleet-sharded serving (repro.traffic.shard): each device
-                # holds a slice of the output channels. Gather them, so the
-                # float model downstream reduces whole rows in the same
-                # order as on one device.
-                P = jax.sharding.PartitionSpec
-                y = jax.lax.with_sharding_constraint(
-                    y, P(*[P.UNCONSTRAINED] * (y.ndim - 1), None))
-        else:
-            y = cim.cim_mf_matmul_ste(x, w, cim_cfg)
-        if _calib_tap.error_active():
-            _calib_tap.record_projection_error(
-                params.get("obs_id"), y, mf_correlate_ref(x, w, hw=True))
-    elif mode == ExecMode.BNN:
-        y = bnn_matmul(x, w)
-    else:  # pragma: no cover
-        raise ValueError(mode)
-    if mode != ExecMode.REGULAR and "alpha" in params:
-        y = y * params["alpha"]
-    if "b" in params:
-        y = y + params["b"]
-    return y
+                y = cim.cim_mf_matmul_ste(x, w, cim_cfg)
+            if _calib_tap.error_active():
+                _calib_tap.record_projection_error(
+                    params.get("obs_id"), y, mf_correlate_ref(x, w, hw=True))
+        elif mode == ExecMode.BNN:
+            y = bnn_matmul(x, w)
+        else:  # pragma: no cover
+            raise ValueError(mode)
+        if mode != ExecMode.REGULAR and "alpha" in params:
+            y = y * params["alpha"]
+        if "b" in params:
+            y = y + params["b"]
+        return y

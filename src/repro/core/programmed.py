@@ -292,10 +292,11 @@ def programmed_partials(x2: jax.Array, prog: ProgrammedMacro,
     if prog.state is not None:
         sx_q = (prog.sx if prog.dac_gains is None
                 else prog.sx * prog.dac_gains)
-        ws = unpack_weight_state(prog.state, cfg)
-        return cim_input_partials(x2, ws, cfg, sx_q, cap_weights,
-                                  comparator_offset, silicon,
-                                  dac_gains=prog.dac_gains)
+        with jax.named_scope("planes"):
+            ws = unpack_weight_state(prog.state, cfg)
+            return cim_input_partials(x2, ws, cfg, sx_q, cap_weights,
+                                      comparator_offset, silicon,
+                                      dac_gains=prog.dac_gains)
     if prog.kernel is not None:
         if cap_weights is not None or comparator_offset is not None:
             raise ValueError(
@@ -305,19 +306,21 @@ def programmed_partials(x2: jax.Array, prog: ProgrammedMacro,
                 "Re-program with use_kernel=False and "
                 "prefer_lossless=False for the legacy knobs.")
         if silicon is None:
-            return cim_kernel_partials(x2, prog.kernel, cfg, prog.sx,
-                                       prog.dac_gains)
+            with jax.named_scope("kernel"):
+                return cim_kernel_partials(x2, prog.kernel, cfg, prog.sx,
+                                           prog.dac_gains)
         if prog.dac_gains is not None:
             raise ValueError(
                 "per-feature DAC gain trims (per-channel sx "
                 "calibration) do not compose with silicon injection; "
                 "program per-tensor scales for silicon fleets")
-        silk = silicon_kernel
-        if silk is None:
-            silk = cim_program_silicon(prog.kernel, silicon, cfg,
-                                       n_chunks=-(-K // cfg.m_columns))
-        return cim_kernel_silicon_partials(x2, prog.kernel, silk, cfg,
-                                           prog.sx, silicon)
+        with jax.named_scope("kernel_silicon"):
+            silk = silicon_kernel
+            if silk is None:
+                silk = cim_program_silicon(prog.kernel, silicon, cfg,
+                                           n_chunks=-(-K // cfg.m_columns))
+            return cim_kernel_silicon_partials(x2, prog.kernel, silk, cfg,
+                                               prog.sx, silicon)
     if inject:
         raise ValueError(
             "variability injection needs per-chunk ADC evaluations, but "
@@ -326,7 +329,8 @@ def programmed_partials(x2: jax.Array, prog: ProgrammedMacro,
             "offset perturb. Re-program the projection with "
             "prefer_lossless=False (program_weights(..., "
             "prefer_lossless=False)).")
-    return _lossless_partials(x2, prog.lossless, cfg, prog.sx, prog.r_w)
+    with jax.named_scope("lossless"):
+        return _lossless_partials(x2, prog.lossless, cfg, prog.sx, prog.r_w)
 
 
 def cim_mf_matmul_programmed(x: jax.Array, prog: ProgrammedMacro,
@@ -559,7 +563,8 @@ def cim_mf_matmul_swapped(x: jax.Array, w: jax.Array, swap: SwappedMacro,
     """
     batch_shape = x.shape[:-1]
     x2 = x.reshape(-1, swap.sched.k)
-    parts = cim_swapped_partials(x2, w, swap, cfg, silicon)
+    with jax.named_scope("swapped"):
+        parts = cim_swapped_partials(x2, w, swap, cfg, silicon)
     y = cim_mf_recombine(parts, swap.sw, swap.sx, cfg)
     return y.reshape(batch_shape + (swap.sched.n,)).astype(x.dtype)
 

@@ -342,9 +342,10 @@ def gqa_apply(p: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
     k = blocks.apply_rope(k, positions, rope_theta)
 
     if cache is None:
-        out = blocked_attention(q, k, v, causal=causal, window=window,
-                                block=attn_block,
-                                block_skip=attn_block_skip)
+        with jax.named_scope("attention"):
+            out = blocked_attention(q, k, v, causal=causal, window=window,
+                                    block=attn_block,
+                                    block_skip=attn_block_skip)
         new_cache = None
     elif prefill_valid is not None:
         # Batched prefill: fold the whole (B, T) prompt slab through one
@@ -360,15 +361,17 @@ def gqa_apply(p: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
         if t > s:
             raise ValueError(f"prefill slab length {t} exceeds cache "
                              f"length {s}")
-        out = blocked_attention(q, k, v, causal=causal, window=window,
-                                block=attn_block,
-                                block_skip=attn_block_skip)
-        mask = (jnp.arange(t)[None, :]
-                < prefill_valid[:, None])[..., None, None]     # (B,T,1,1)
-        k_cache = cache["k"].at[:, :t].set(
-            jnp.where(mask, k.astype(cache["k"].dtype), cache["k"][:, :t]))
-        v_cache = cache["v"].at[:, :t].set(
-            jnp.where(mask, v.astype(cache["v"].dtype), cache["v"][:, :t]))
+        with jax.named_scope("attention"):
+            out = blocked_attention(q, k, v, causal=causal, window=window,
+                                    block=attn_block,
+                                    block_skip=attn_block_skip)
+        with jax.named_scope("kv_write"):
+            mask = (jnp.arange(t)[None, :]
+                    < prefill_valid[:, None])[..., None, None]  # (B,T,1,1)
+            k_cache = cache["k"].at[:, :t].set(jnp.where(
+                mask, k.astype(cache["k"].dtype), cache["k"][:, :t]))
+            v_cache = cache["v"].at[:, :t].set(jnp.where(
+                mask, v.astype(cache["v"].dtype), cache["v"][:, :t]))
         new_cache = {"k": k_cache, "v": v_cache,
                      "len": cache["len"] + prefill_valid}
     else:
@@ -387,30 +390,37 @@ def gqa_apply(p: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
         if use_flash_sp:
             dp = (pctx.cfg.dp_axes if len(pctx.cfg.dp_axes) > 1
                   else pctx.cfg.dp_axes[0])
-            out, k_cache, v_cache = flash_decode_sharded(
-                q[:, 0], k, v, cache["k"], cache["v"], idx,
-                mesh=pctx.mesh, dp=dp, tp=pctx.cfg.tp_axis)
+            with jax.named_scope("attention"):
+                out, k_cache, v_cache = flash_decode_sharded(
+                    q[:, 0], k, v, cache["k"], cache["v"], idx,
+                    mesh=pctx.mesh, dp=dp, tp=pctx.cfg.tp_axis)
             out = out[:, None]
             y = blocks.proj_apply(
                 p["o"], out.reshape(b, t, n_heads * head_dim), mode, **kw)
             return y, {"k": k_cache, "v": v_cache, "len": idx + 1}
         widx = idx % s if is_ring else idx
-        k_cache = jax.vmap(
-            lambda c, kv, i: jax.lax.dynamic_update_slice(
-                c, kv, (i, 0, 0)))(cache["k"], k.astype(cache["k"].dtype),
-                                   widx)
-        v_cache = jax.vmap(
-            lambda c, kv, i: jax.lax.dynamic_update_slice(
-                c, kv, (i, 0, 0)))(cache["v"], v.astype(cache["v"].dtype),
-                                   widx)
-        if is_ring:
-            pos_ok = jnp.arange(s)[None, :] < jnp.minimum(idx + 1, s)[:, None]
-        else:
-            pos_ok = jnp.arange(s)[None, :] < (idx + 1)[:, None]
-            if window is not None:
-                pos_ok &= jnp.arange(s)[None, :] > (idx[:, None] - window)
-        m, l, o = decode_attention_partial(q[:, 0], k_cache, v_cache, pos_ok)
-        out = (o / jnp.maximum(l, 1e-30)[..., None])[:, None].astype(q.dtype)
+        with jax.named_scope("kv_write"):
+            k_cache = jax.vmap(
+                lambda c, kv, i: jax.lax.dynamic_update_slice(
+                    c, kv, (i, 0, 0)))(cache["k"],
+                                       k.astype(cache["k"].dtype), widx)
+            v_cache = jax.vmap(
+                lambda c, kv, i: jax.lax.dynamic_update_slice(
+                    c, kv, (i, 0, 0)))(cache["v"],
+                                       v.astype(cache["v"].dtype), widx)
+        with jax.named_scope("attention"):
+            if is_ring:
+                pos_ok = (jnp.arange(s)[None, :]
+                          < jnp.minimum(idx + 1, s)[:, None])
+            else:
+                pos_ok = jnp.arange(s)[None, :] < (idx + 1)[:, None]
+                if window is not None:
+                    pos_ok &= (jnp.arange(s)[None, :]
+                               > (idx[:, None] - window))
+            m, l, o = decode_attention_partial(q[:, 0], k_cache, v_cache,
+                                               pos_ok)
+            out = (o / jnp.maximum(l, 1e-30)[..., None])[:, None].astype(
+                q.dtype)
         new_cache = {"k": k_cache, "v": v_cache, "len": idx + 1}
 
     y = blocks.proj_apply(

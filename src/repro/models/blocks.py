@@ -150,6 +150,7 @@ def embed_apply(p: dict, tokens: jax.Array) -> jax.Array:
 
 def lm_head_apply(p: dict, x: jax.Array, *, tied_table: Optional[jax.Array]
                   = None) -> jax.Array:
-    if tied_table is not None:
-        return x @ tied_table.T
-    return proj_apply(p, x)
+    with jax.named_scope("lm_head"):
+        if tied_table is not None:
+            return x @ tied_table.T
+        return proj_apply(p, x)

@@ -2,8 +2,8 @@
 
 ``repro.obs`` is the observability layer for the serving stack:
 
-* :mod:`repro.obs.trace` — process-local ring-buffered event bus with
-  in-jit emission (unordered ``io_callback``, trace-once).
+* :mod:`repro.obs.trace` — process-local ring-buffered event bus, and
+  host spans that also land in a running ``jax.profiler`` trace.
 * :mod:`repro.obs.metrics` — typed registry (counters / gauges /
   fixed-bucket histograms) backing the serve and traffic reports.
 * :mod:`repro.obs.export` — Prometheus text exposition + JSONL traces,
@@ -42,7 +42,6 @@ from repro.obs.trace import (
     bus,
     detail_enabled,
     emit,
-    emit_decode_tick,
     enabled,
     install,
     span,
@@ -65,7 +64,6 @@ __all__ = [
     "detail_enabled",
     "drift_story",
     "emit",
-    "emit_decode_tick",
     "enabled",
     "fleet_heatmap",
     "from_events",

@@ -5,41 +5,31 @@ End-of-run snapshots (``ServeReport`` / ``TrafficReport`` /
 slot/tenant/request, and *why*. Every event is a :class:`TraceEvent` —
 a kind from the serving taxonomy (``program``, ``reload``, ``recal``,
 ``retrim``, ``drift_probe``, ``admit`` / ``shed`` / ``evict``,
-``prefill_wave``, ``decode_tick``, ``sanitize``, ...), a monotonic
-timestamp, the engine's input-stream index, optional slot / request /
-layer coordinates, and a JSON-safe payload (nJ / bits figures pulled
-from the Eq. 4 roll-up, drift residues, queue depths).
+``prefill_wave``, ``sanitize``, ...), a monotonic timestamp, the engine's
+input-stream index, optional slot / request / layer coordinates, and a
+JSON-safe payload (nJ / bits figures pulled from the Eq. 4 roll-up, drift
+residues, queue depths).
 
-Design constraints, in order:
+Two sinks, one API:
 
-1. **Zero cost when off, bounded cost when on.** Host-side emitters are
-   one ``None`` check; the in-jit decode-tick emitter is staged into a
-   SEPARATE compiled twin of the decode step that exists only when the
-   engine was constructed with tracing enabled — a tracing-off engine
-   compiles exactly the program it compiles today and its decoded
-   tokens are BITWISE identical (gated in ``benchmarks/obs_report.py``).
-   Because any host callback in a jitted program forfeits the C++
-   fast-dispatch path (milliseconds per call on CPU), a tracing engine
-   dispatches the traced twin on a sampling cadence
-   (``trace_tick_interval``, default every 128th tick) and the pure
-   program otherwise — ``decode_tick`` events are a sampled timeline
-   (each names its stream index, so gaps are explicit), while the
-   metrics counters remain tick-exact. The overhead gate (<= 5% decode
-   tok/s, same bench) holds at the default cadence.
-2. **No retracing.** The in-jit emitter follows the calibration tap's
-   ``io_callback`` discipline (unordered, staged at trace time, routed
-   through a module-global read at FIRE time): the jitted decode step is
-   traced once per shape whether or not a bus is installed, and
-   installing / swapping a bus between runs never invalidates the cache.
-3. **Bounded memory.** The bus is a ring buffer: the newest ``capacity``
-   events win, ``dropped`` counts what the ring evicted, so a week-long
-   serve cannot OOM the host.
+1. **The bus**, for the serving story. Host-side emitters are one
+   ``None`` check when no bus is installed. The bus is a ring buffer: the
+   newest ``capacity`` events win, ``dropped`` counts what the ring
+   evicted, so a week-long serve cannot OOM the host.
+2. **The profiler**, for time. :func:`span` always opens a
+   ``jax.profiler.TraceAnnotation``, so a running ``jax.profiler`` trace
+   holds the program's host spans (``<layer>.<part>``: ``batcher.tick``,
+   ``serve.step``, ``serve.sync``, ...) on the same clock as the device's
+   operations. With no profiler running that costs about a microsecond
+   per span. The device side needs no host code: ``jax.named_scope``
+   marks the step's layers (``attention``, ``kv_write``,
+   ``mf_projection``/<route>, ``lm_head``, ``sampling``) in the compiled
+   program's op metadata.
 
 The bus is process-local and deliberately global (one serving process =
 one timeline); concurrent engines tag events with their ``engine`` field
 and readers filter. Not thread-safe beyond CPython list-append atomicity
-— the serving loop is single-threaded, and unordered ``io_callback``s
-fire on the main thread between dispatches.
+— the serving loop is single-threaded.
 """
 # repro-lint: module=observability
 
@@ -49,6 +39,8 @@ import dataclasses
 import time
 from contextlib import contextmanager
 from typing import Any, Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Events whose payloads may carry large per-slot arrays (drift residue /
 # tier vectors). They are emitted only when the installed bus asks for
@@ -194,52 +186,34 @@ def emit(kind: str, *, stream: Optional[int] = None,
                            engine=engine, data=data))
 
 
-@contextmanager
-def span(kind: str, **fields: Any) -> Iterator[None]:
-    """Emit ``kind`` once on exit with the block's duration in ``dur_ns``
-    (single-event spans: cheap, and ring-eviction cannot orphan a
-    begin/end pair)."""
+class _BusSpan:
+    """A profiler span that also emits ``kind`` once on exit, with the
+    block's duration in ``dur_ns`` (single-event spans: cheap, and
+    ring-eviction cannot orphan a begin/end pair)."""
+
+    __slots__ = ("kind", "fields", "t0", "annotation")
+
+    def __init__(self, kind: str, fields: dict):
+        self.kind, self.fields = kind, fields
+        self.annotation = TraceAnnotation(kind, **fields)
+
+    def __enter__(self) -> None:
+        self.annotation.__enter__()
+        self.t0 = time.monotonic_ns()
+
+    def __exit__(self, *exc) -> None:
+        emit(self.kind, dur_ns=time.monotonic_ns() - self.t0, **self.fields)
+        self.annotation.__exit__(*exc)
+
+
+def span(kind: str, **fields: Any):
+    """Context manager around a block of host work: a
+    ``jax.profiler.TraceAnnotation`` named ``kind`` with ``fields`` as its
+    arguments (recorded only while a profiler trace runs), plus one bus
+    event on exit when a bus is installed.
+
+    A field value reaches the profiler as text, and the profiler splits
+    its arguments at commas: join lists with spaces."""
     if _BUS is None:
-        yield
-        return
-    t0 = time.monotonic_ns()
-    try:
-        yield
-    finally:
-        emit(kind, dur_ns=time.monotonic_ns() - t0, **fields)
-
-
-# ---------------------------------------------------------------------------
-# In-jit emission (the calib-tap io_callback pattern).
-# ---------------------------------------------------------------------------
-
-def emit_decode_tick(step, tokens, active,
-                     engine: Optional[int] = None) -> None:
-    """Stage one unordered ``io_callback`` emitting a ``decode_tick``
-    event per execution of the enclosing jitted program.
-
-    Call ONLY under trace, and only when the engine decided at
-    construction that this compiled program is a traced one — the
-    callback routes through the module-global bus at fire time, so the
-    staged program keeps working (or cheaply no-ops) as buses come and
-    go, without retracing. ``step`` is the engine's input-stream counter,
-    ``tokens`` the sampled next-token vector, ``active`` the number of
-    occupied slots this tick; ``engine`` is a small static tag captured
-    into the compiled program (NOT a traced operand).
-    """
-    from functools import partial
-
-    import jax.numpy as jnp
-    from jax.experimental import io_callback
-
-    io_callback(partial(_decode_tick_host, engine), None,
-                jnp.asarray(step, jnp.int32),
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(active, jnp.int32), ordered=False)
-
-
-def _decode_tick_host(engine, step, tokens, active) -> None:
-    if _BUS is None:
-        return
-    emit("decode_tick", stream=int(step), engine=engine,
-         active=int(active), tokens=[int(t) for t in tokens])
+        return TraceAnnotation(kind, **fields)
+    return _BusSpan(kind, fields)

@@ -66,38 +66,29 @@ from repro.obs.metrics import MetricsRegistry
 _ENGINE_TAGS = itertools.count(1)
 
 
-def make_serve_step(cfg: ModelConfig, pctx=None, temperature: float = 0.0,
-                    trace_tag: Optional[int] = None) -> Callable:
+def make_serve_step(cfg: ModelConfig, pctx=None,
+                    temperature: float = 0.0) -> Callable:
     """(params, cache, tokens, rng, step) -> (next_tokens, logits, cache).
 
     ``step`` is the engine's input-stream counter (decode steps + prefill
     calls), threaded through :func:`repro.core.cim.conversion_clock` so
     per-conversion thermal dither decorrelates across stream steps. It is
     unused (and free) when the exec tree carries no thermal silicon.
-
-    ``trace_tag`` (an engine's trace id) stages a ``decode_tick`` trace
-    emission into the compiled program — an unordered ``io_callback``
-    that routes through :mod:`repro.obs.trace`'s module-global bus at
-    FIRE time, so buses come and go without retracing, and a program
-    built with ``trace_tag=None`` is exactly today's program (the
-    bitwise-parity gate of ``benchmarks/obs_report.py``). Traced
-    programs take one extra operand, ``active`` (occupied slots this
-    tick), which rides the event payload.
     """
     pctx = pctx or T.ParallelContext()
     from repro.core import cim
 
-    def serve_step(params, cache, tokens, rng, step=0, active=0):
+    def serve_step(params, cache, tokens, rng, step=0):
         with cim.conversion_clock(step):
             logits, new_cache = T.lm_decode_step(params, cache, tokens,
                                                  cfg, pctx)
-        if temperature > 0.0:
-            nxt = jax.random.categorical(rng, logits / temperature, axis=-1)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
-        nxt = nxt.astype(jnp.int32)
-        if trace_tag is not None:
-            obs_trace.emit_decode_tick(step, nxt, active, engine=trace_tag)
+        with jax.named_scope("sampling"):
+            if temperature > 0.0:
+                nxt = jax.random.categorical(rng, logits / temperature,
+                                             axis=-1)
+            else:
+                nxt = jnp.argmax(logits, axis=-1)
+            nxt = nxt.astype(jnp.int32)
         return nxt, logits, new_cache
 
     return serve_step
@@ -181,8 +172,7 @@ class ServeEngine:
                  temperature: float = 0.0, eos_id: Optional[int] = None,
                  seed: int = 0, program: bool = True, calibration=None,
                  fleet=None, batched_prefill: Optional[bool] = None,
-                 silicon=None, silicon_key=None, drift=None,
-                 tracing: bool = False, trace_tick_interval: int = 128):
+                 silicon=None, silicon_key=None, drift=None):
         self.params = params
         self.cfg = cfg
         self.slots = slots
@@ -206,25 +196,10 @@ class ServeEngine:
         # ``drift`` (a repro.silicon.drift DriftPolicy) probes the live
         # datapath against the calibration baseline every
         # ``check_interval`` streams and auto-recalibrates on alarm.
-        # ``tracing=True`` compiles a SECOND decode program with the
-        # in-jit ``decode_tick`` emission staged (see ``make_serve_step``
-        # — the ONLY observability decision baked into a compiled
-        # program; host-side events and metrics are always live and cost
-        # one global read when no bus/reader is attached). Any host
-        # callback in a jitted program costs the C++ fast-dispatch path
-        # (milliseconds per call on CPU), so the traced program runs on a
-        # SAMPLING CADENCE: every ``trace_tick_interval``-th decode tick
-        # dispatches it (and emits), every other tick runs the pure
-        # program. ``decode_tick`` events are therefore a sampled
-        # timeline; the metrics counters stay tick-exact. Interval 1
-        # traces every tick (tests; short diagnostic runs).
+        # Host work is marked with ``obs_trace.span`` (``serve.*``), which
+        # a running ``jax.profiler`` trace records and a bus hears; the
+        # compiled programs carry named scopes only (op metadata).
         self._exec_params = params
-        self.tracing = bool(tracing)
-        if trace_tick_interval < 1:
-            raise ValueError(
-                f"trace_tick_interval must be >= 1, "
-                f"got {trace_tick_interval}")
-        self.trace_tick_interval = int(trace_tick_interval)
         self.trace_tag = next(_ENGINE_TAGS)
         # The metrics registry every stream/health counter lives in:
         # ``ServeReport`` (and the traffic lab's ``TrafficReport``) are
@@ -241,6 +216,9 @@ class ServeEngine:
             "serve_prefill_calls_total", "batched-prefill waves")
         self._c_prefill_tokens = m.counter(
             "serve_prefill_tokens_total", "prompt tokens ingested")
+        self._c_prefill_slab_tokens = m.counter(
+            "serve_prefill_slab_tokens_total",
+            "prompt-slab positions computed (slots x bucket per wave)")
         self._c_drift_checks = m.counter(
             "serve_drift_checks_total", "drift probes run")
         self._c_drift_alarms = m.counter(
@@ -342,14 +320,8 @@ class ServeEngine:
             self._program(scales)
             self.programmed = True
         self.cache = T.lm_init_cache(cfg, slots, max_len)
-        # The pure program (exactly today's); compiled lazily on first
-        # untraced tick, so an interval-1 tracing engine never pays for
-        # it. The traced twin exists only when tracing is on.
         self.step_fn = jax.jit(make_serve_step(cfg,
                                                temperature=temperature))
-        self._traced_step_fn = jax.jit(make_serve_step(
-            cfg, temperature=temperature,
-            trace_tag=self.trace_tag)) if self.tracing else None
         self.requests: list[Optional[Request]] = [None] * slots
         self._feed = np.zeros((slots,), np.int32)       # next token to feed
         self._prompt_left = np.zeros((slots,), np.int64)
@@ -537,28 +509,30 @@ class ServeEngine:
         instead of one decode tick per token. Returns the number of
         requests admitted.
         """
-        self._validate(reqs)
-        free = self.free_slots
-        take = reqs[:len(free)]
-        if not take:
-            return 0
-        sel = free[:len(take)]
-        for s, req in zip(sel, take):
-            self.requests[s] = req
-            self._feed[s] = req.prompt[0]
-            self._prompt_left[s] = len(req.prompt) - 1
-        pad = np.full((self.slots,), sel[0], np.int32)
-        pad[:len(sel)] = sel
-        self.cache = _reset_slots(self.cache, jnp.asarray(pad))
-        if obs_trace.enabled():
-            self._emit("admit", stream=self.stream_index, slots=len(sel),
-                       prompt_tokens=sum(len(r.prompt) for r in take))
-        for hook in self.admission_hooks:
-            hook(list(zip(sel, take)))
-        if self.batched_prefill:
-            self._prefill_wave([(s, r) for s, r in zip(sel, take)
-                                if len(r.prompt) > 1])
-        return len(take)
+        with obs_trace.span("serve.admit"):
+            self._validate(reqs)
+            free = self.free_slots
+            take = reqs[:len(free)]
+            if not take:
+                return 0
+            sel = free[:len(take)]
+            for s, req in zip(sel, take):
+                self.requests[s] = req
+                self._feed[s] = req.prompt[0]
+                self._prompt_left[s] = len(req.prompt) - 1
+            pad = np.full((self.slots,), sel[0], np.int32)
+            pad[:len(sel)] = sel
+            self.cache = _reset_slots(self.cache, jnp.asarray(pad))
+            if obs_trace.enabled():
+                self._emit("admit", stream=self.stream_index,
+                           slots=len(sel),
+                           prompt_tokens=sum(len(r.prompt) for r in take))
+            for hook in self.admission_hooks:
+                hook(list(zip(sel, take)))
+            if self.batched_prefill:
+                self._prefill_wave([(s, r) for s, r in zip(sel, take)
+                                    if len(r.prompt) > 1])
+            return len(take)
 
     def _prefill_wave(self, wave: list[tuple[int, Request]]) -> None:
         """Ingest the admitted prompts' first ``len - 1`` tokens in one
@@ -569,26 +543,28 @@ class ServeEngine:
         ``valid = 0`` and are untouched."""
         if not wave:
             return
-        t_max = max(len(r.prompt) - 1 for _, r in wave)
-        t_b = min(1 << (t_max - 1).bit_length(), self.max_len)
-        tokens = np.zeros((self.slots, t_b), np.int32)
-        valid = np.zeros((self.slots,), np.int32)
-        for s, req in wave:
-            n = len(req.prompt) - 1
-            tokens[s, :n] = req.prompt[:n]
-            valid[s] = n
-            self._feed[s] = req.prompt[n]
-            self._prompt_left[s] = 0
-        stream = self.stream_index
-        self.cache = self._prefill_fn(self._exec_params, self.cache,
-                                      jnp.asarray(tokens),
-                                      jnp.asarray(valid),
-                                      jnp.int32(stream))
-        self._c_prefill_calls.inc()
-        self._c_prefill_tokens.inc(int(valid.sum()))
-        self._emit("prefill_wave", stream=stream, slots=len(wave),
-                   tokens=int(valid.sum()), bucket=t_b)
-        self._after_stream()
+        with obs_trace.span("serve.prefill"):
+            t_max = max(len(r.prompt) - 1 for _, r in wave)
+            t_b = min(1 << (t_max - 1).bit_length(), self.max_len)
+            tokens = np.zeros((self.slots, t_b), np.int32)
+            valid = np.zeros((self.slots,), np.int32)
+            for s, req in wave:
+                n = len(req.prompt) - 1
+                tokens[s, :n] = req.prompt[:n]
+                valid[s] = n
+                self._feed[s] = req.prompt[n]
+                self._prompt_left[s] = 0
+            stream = self.stream_index
+            self.cache = self._prefill_fn(self._exec_params, self.cache,
+                                          jnp.asarray(tokens),
+                                          jnp.asarray(valid),
+                                          jnp.int32(stream))
+            self._c_prefill_calls.inc()
+            self._c_prefill_tokens.inc(int(valid.sum()))
+            self._c_prefill_slab_tokens.inc(tokens.size)
+            self._emit("prefill_wave", stream=stream, slots=len(wave),
+                       tokens=int(valid.sum()), bucket=t_b)
+            self._after_stream()
 
     def _validate(self, reqs: list[Request]) -> None:
         """Reject malformed requests BEFORE any engine state mutates."""
@@ -610,50 +586,41 @@ class ServeEngine:
 
     def step(self) -> None:
         """One engine tick: decode every occupied slot by one token."""
-        self._rng, sub = jax.random.split(self._rng)
-        tokens = jnp.asarray(self._feed)
-        step_idx = jnp.int32(self.stream_index)
-        cache_before = self.cache if self._sanitizer is not None else None
-        if self.tracing and \
-                int(self._c_decode_steps.value) \
-                % self.trace_tick_interval == 0:
-            # Sampled tick: dispatch the traced twin program. One extra
-            # int32 operand (occupied slots) rides the staged decode_tick
-            # emission. Same jaxpr every sampled tick — the operand is an
-            # array, not a Python constant — so the twin is traced once
-            # and the cadence never recompiles anything.
-            active = jnp.int32(
-                sum(r is not None for r in self.requests))
-            nxt, logits, self.cache = self._traced_step_fn(
-                self._exec_params, self.cache, tokens, sub, step_idx,
-                active)
-        else:
-            nxt, logits, self.cache = self.step_fn(self._exec_params,
-                                                   self.cache, tokens,
-                                                   sub, step_idx)
-        if self._sanitizer is not None:
-            self._sanitizer.check_step(self, cache_before, tokens, sub,
-                                       step_idx, logits)
-        self._c_decode_steps.inc()
-        nxt = np.asarray(nxt)
-        for s, req in enumerate(self.requests):
-            if req is None:
-                continue
-            if self._prompt_left[s] > 0:
-                # still ingesting the prompt: feed the next prompt token
-                k = len(req.prompt) - int(self._prompt_left[s])
-                self._feed[s] = req.prompt[k]
-                self._prompt_left[s] -= 1
-                continue
-            tok = int(nxt[s])
-            req.out.append(tok)
-            self._c_decode_tokens.inc()
-            self._feed[s] = tok
-            if (self.eos_id is not None and tok == self.eos_id) or \
-                    len(req.out) >= req.max_new_tokens:
-                req.done = True
-                self.requests[s] = None
-        self._after_stream()
+        with obs_trace.span("serve.step"):
+            with obs_trace.span("serve.dispatch"):
+                self._rng, sub = jax.random.split(self._rng)
+                tokens = jnp.asarray(self._feed)
+                step_idx = jnp.int32(self.stream_index)
+                cache_before = (self.cache if self._sanitizer is not None
+                                else None)
+                nxt, logits, self.cache = self.step_fn(self._exec_params,
+                                                       self.cache, tokens,
+                                                       sub, step_idx)
+            if self._sanitizer is not None:
+                self._sanitizer.check_step(self, cache_before, tokens, sub,
+                                           step_idx, logits)
+            self._c_decode_steps.inc()
+            with obs_trace.span("serve.sync"):
+                nxt = np.asarray(nxt)
+            with obs_trace.span("serve.bookkeep"):
+                for s, req in enumerate(self.requests):
+                    if req is None:
+                        continue
+                    if self._prompt_left[s] > 0:
+                        # still ingesting the prompt: feed the next one
+                        k = len(req.prompt) - int(self._prompt_left[s])
+                        self._feed[s] = req.prompt[k]
+                        self._prompt_left[s] -= 1
+                        continue
+                    tok = int(nxt[s])
+                    req.out.append(tok)
+                    self._c_decode_tokens.inc()
+                    self._feed[s] = tok
+                    if (self.eos_id is not None and tok == self.eos_id) \
+                            or len(req.out) >= req.max_new_tokens:
+                        req.done = True
+                        self.requests[s] = None
+            self._after_stream()
 
     # -- silicon aging + drift monitoring -----------------------------------
 
@@ -669,22 +636,23 @@ class ServeEngine:
                 and obs_trace.enabled()):
             self._emit("reload", stream=self.stream_index,
                        **self._stream_reload_payload)
-        if self.silicon is None and self._monitor is None:
+        if not self._drifting and self._monitor is None:
             return
-        streams = self.stream_index
-        if self.silicon is not None and self._drifting:
-            # A fleet with zero drift sigmas never changes with age, so
-            # static-silicon engines skip the per-token aging entirely.
-            from repro.silicon.instance import age
-            self.silicon = age(self.silicon, 1)
-            interval = (self.drift.silicon_update_interval
-                        if self.drift is not None
-                        else self._SILICON_UPDATE_DEFAULT)
-            if streams % max(interval, 1) == 0:
-                self._refresh_silicon()
-        if (self._monitor is not None
-                and streams % max(self.drift.check_interval, 1) == 0):
-            self._drift_check(streams)
+        with obs_trace.span("serve.after_stream"):
+            streams = self.stream_index
+            if self._drifting:
+                # A fleet with zero drift sigmas never changes with age,
+                # so static-silicon engines skip the per-token aging.
+                from repro.silicon.instance import age
+                self.silicon = age(self.silicon, 1)
+                interval = (self.drift.silicon_update_interval
+                            if self.drift is not None
+                            else self._SILICON_UPDATE_DEFAULT)
+                if streams % max(interval, 1) == 0:
+                    self._refresh_silicon()
+            if (self._monitor is not None
+                    and streams % max(self.drift.check_interval, 1) == 0):
+                self._drift_check(streams)
 
     def _drift_check(self, streams: int) -> None:
         self._c_drift_checks.inc()

@@ -177,85 +177,103 @@ class ContinuousBatcher:
         while i < len(arrivals) or queue or self._slot_map:
             if ticks >= max_ticks:
                 break
-            now = clock.now
-            # 1) pull arrivals whose timestamp has passed
-            while i < len(arrivals) and \
-                    arrivals[i].t_arrival_s <= now:
-                tr = arrivals[i]
-                i += 1
-                if len(queue) >= adm.max_queue or not self._admissible(tr):
-                    self._reject(tr, now,
-                                 "queue_full"
-                                 if len(queue) >= adm.max_queue
-                                 else "inadmissible")
+            with obs_trace.span("batcher.tick"):
+                with obs_trace.span("batcher.queue"):
+                    now = clock.now
+                    # 1) pull arrivals whose timestamp has passed
+                    while i < len(arrivals) and \
+                            arrivals[i].t_arrival_s <= now:
+                        tr = arrivals[i]
+                        i += 1
+                        if len(queue) >= adm.max_queue or \
+                                not self._admissible(tr):
+                            self._reject(tr, now,
+                                         "queue_full"
+                                         if len(queue) >= adm.max_queue
+                                         else "inadmissible")
+                            continue
+                        tr.state = "queued"
+                        queue.append(tr)
+                    # (steps 3-5 are no-ops for an idle engine)
+                    idle = (not queue and not self._slot_map
+                            and i < len(arrivals))
+                    # 3) shed queued requests that already missed their
+                    #    TTFT SLO
+                    if adm.drop_late:
+                        late = [t for t in queue if now > t.ttft_deadline_s]
+                        for tr in late:
+                            queue.remove(tr)
+                            self._reject(tr, now, "ttft_slo")
+                    # 4) evict in-flight requests past their completion
+                    #    deadline
+                    if adm.evict_past_deadline:
+                        for slot, tr in list(self._slot_map.items()):
+                            if now > tr.deadline_s and not tr.serve.done:
+                                # engine.evict emits the slot-side "evict"
+                                # event (freed tokens); this one joins it
+                                # to the rid.
+                                eng.evict(slot)
+                                obs_trace.emit("evict_sched", rid=tr.rid,
+                                               slot=slot,
+                                               engine=eng.trace_tag,
+                                               reason="deadline")
+                                del self._slot_map[slot]
+                                tr.state = "evicted"
+                                tr.t_done_s = now
+                    # 5) wave-fill free slots: priority first, FIFO within
+                    wave = []
+                    if queue and eng.free_slots:
+                        queue.sort(key=lambda t: (t.priority,
+                                                  t.t_arrival_s, t.rid))
+                        n = min(len(queue), len(eng.free_slots))
+                        wave, queue = queue[:n], queue[n:]
+                if idle:
+                    # 2) idle engine, empty queue: jump to the next
+                    #    arrival instead of burning empty decode ticks
+                    with obs_trace.span("batcher.clock"):
+                        clock.fast_forward(arrivals[i].t_arrival_s)
                     continue
-                tr.state = "queued"
-                queue.append(tr)
-            # 2) idle engine, empty queue: jump to the next arrival
-            #    instead of burning empty decode ticks
-            if not queue and not self._slot_map and i < len(arrivals):
-                clock.fast_forward(arrivals[i].t_arrival_s)
-                continue
-            # 3) shed queued requests that already missed their TTFT SLO
-            if adm.drop_late:
-                late = [t for t in queue if now > t.ttft_deadline_s]
-                for tr in late:
-                    queue.remove(tr)
-                    self._reject(tr, now, "ttft_slo")
-            # 4) evict in-flight requests past their completion deadline
-            if adm.evict_past_deadline:
-                for slot, tr in list(self._slot_map.items()):
-                    if now > tr.deadline_s and not tr.serve.done:
-                        # engine.evict emits the slot-side "evict" event
-                        # (freed tokens); this one joins it to the rid.
-                        eng.evict(slot)
-                        obs_trace.emit("evict_sched", rid=tr.rid,
-                                       slot=slot,
-                                       engine=eng.trace_tag,
-                                       reason="deadline")
-                        del self._slot_map[slot]
-                        tr.state = "evicted"
-                        tr.t_done_s = now
-            # 5) wave-fill free slots: priority first, FIFO within
-            if queue and eng.free_slots:
-                queue.sort(key=lambda t: (t.priority, t.t_arrival_s,
-                                          t.rid))
-                n = min(len(queue), len(eng.free_slots))
-                wave, queue = queue[:n], queue[n:]
-                sreqs = []
-                for tr in wave:
-                    tr.serve = Request(prompt=tr.prompt,
-                                       max_new_tokens=tr.max_new_tokens)
-                    self._by_serve[id(tr.serve)] = tr
-                    tr.state = "running"
-                    tr.t_admit_s = now
-                    sreqs.append(tr.serve)
-                admitted = eng.submit_many(sreqs)
-                assert admitted == len(sreqs), \
-                    "wave sized to free_slots must admit fully"
-                if eng.batched_prefill and \
-                        any(len(r.prompt) > 1 for r in sreqs):
-                    clock.on_prefill()
-            # 6) one decode tick for every occupied slot
-            occupied.append(len(self._slot_map))
-            queue_depth.append(len(queue))
-            self._g_queue.set(len(queue))
-            eng.step()
-            clock.on_decode()
-            ticks += 1
-            now = clock.now
-            # 7) observe first tokens and completions
-            for slot, tr in list(self._slot_map.items()):
-                if tr.t_first_token_s is None and tr.serve.out:
-                    tr.t_first_token_s = now
-                if tr.serve.done:
-                    tr.state = "completed"
-                    tr.t_done_s = now
-                    self._c_completed.inc()
-                    if tr.ttft_s is not None:
-                        self._h_ttft.observe(tr.ttft_s)
-                    self._h_latency.observe(tr.latency_s)
-                    del self._slot_map[slot]
+                if wave:
+                    rids = " ".join(str(tr.rid) for tr in wave)
+                    with obs_trace.span("batcher.admit", rids=rids):
+                        sreqs = []
+                        for tr in wave:
+                            tr.serve = Request(
+                                prompt=tr.prompt,
+                                max_new_tokens=tr.max_new_tokens)
+                            self._by_serve[id(tr.serve)] = tr
+                            tr.state = "running"
+                            tr.t_admit_s = now
+                            sreqs.append(tr.serve)
+                        admitted = eng.submit_many(sreqs)
+                        assert admitted == len(sreqs), \
+                            "wave sized to free_slots must admit fully"
+                    if eng.batched_prefill and \
+                            any(len(r.prompt) > 1 for r in sreqs):
+                        with obs_trace.span("batcher.clock"):
+                            clock.on_prefill()
+                # 6) one decode tick for every occupied slot
+                occupied.append(len(self._slot_map))
+                queue_depth.append(len(queue))
+                self._g_queue.set(len(queue))
+                eng.step()
+                with obs_trace.span("batcher.clock"):
+                    clock.on_decode()
+                ticks += 1
+                with obs_trace.span("batcher.observe"):
+                    # 7) observe first tokens and completions
+                    now = clock.now
+                    for slot, tr in list(self._slot_map.items()):
+                        if tr.t_first_token_s is None and tr.serve.out:
+                            tr.t_first_token_s = now
+                        if tr.serve.done:
+                            tr.state = "completed"
+                            tr.t_done_s = now
+                            self._c_completed.inc()
+                            if tr.ttft_s is not None:
+                                self._h_ttft.observe(tr.ttft_s)
+                            self._h_latency.observe(tr.latency_s)
+                            del self._slot_map[slot]
 
         # drain bookkeeping for anything still alive at the tick budget
         out_of_ticks = bool(queue or self._slot_map

@@ -74,7 +74,6 @@ def shard_engine(engine, mesh) -> dict:
         return call
 
     engine.step_fn = under_mesh(engine.step_fn)
-    engine._traced_step_fn = under_mesh(engine._traced_step_fn)
     engine._prefill_fn = under_mesh(engine._prefill_fn)
 
     def _reput(eng):

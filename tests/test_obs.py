@@ -1,14 +1,16 @@
 """Fleet telemetry (``repro.obs``): bus, metrics, exports, engine taps.
 
-What is pinned here, in the order the ISSUE lists it:
+What is pinned here:
 
-  * in-jit ``decode_tick`` emission is trace-once — the traced twin
-    program compiles exactly once however many ticks run, and buses can
-    be installed/swapped between ticks without retracing (the same
-    discipline as the calibration lab's ``collect_stats``);
-  * tracing disabled (or merely a bus installed against an untraced
-    engine) leaves decoded tokens BITWISE identical on the pinned,
-    swapped (rounds > 1) and silicon serving paths;
+  * the program's host spans (``batcher.*``, ``serve.*``) land in a
+    running ``jax.profiler`` trace, nested as the code nests them, and
+    cost no bus event when no bus is installed;
+  * the compiled step programs carry the named scopes (``attention``,
+    ``kv_write``, ``mf_projection`` with the route taken) in their op
+    metadata, and the engine compiles one decode program;
+  * a running profiler or an installed bus leaves decoded tokens BITWISE
+    identical on the pinned, swapped (rounds > 1) and silicon paths;
+  * the prefill slab counter counts slots x bucket per wave;
   * histogram merge is order-invariant; windowed counter deltas sum
     exactly even when a recalibration lands inside a window;
   * Prometheus text exposition and trace JSONL both round-trip;
@@ -31,7 +33,7 @@ from repro.configs.base import MFTechniqueConfig, ModelConfig
 from repro.core.cim import CimConfig
 from repro.models import transformer as T
 from repro.obs import trace as obs_trace
-from repro.serve.engine import Request, ServeEngine, make_serve_step
+from repro.serve.engine import Request, ServeEngine
 
 CIM = CimConfig(4, 4, 5, 31)
 
@@ -91,94 +93,148 @@ class TestTraceBus:
         assert ev.kind == "recal" and ev.data["dur_ns"] >= 0
 
 
-class TestInJitEmission:
-    def test_traced_twin_traces_once_across_ticks_and_buses(self):
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` trace; returns its result and
+    the program's host spans as (name, start_ns, end_ns, stats)."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb")))[-1]
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+              dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(("serve.", "batcher."))]
+    return out, spans
+
+
+def _engine(kind, cfg, params, **kw):
+    from repro.silicon.instance import SiliconConfig
+    kw = dict(dict(slots=2, max_len=16, batched_prefill=False), **kw)
+    if kind == "swapped":
+        return ServeEngine(params, cfg, fleet=Fleet(n_macros=8, cfg=CIM),
+                           **kw)
+    fleet = Fleet(n_macros=1024, cfg=cfg.mf.cim)
+    if kind in ("silicon", "kernel_silicon"):
+        return ServeEngine(params, cfg, fleet=fleet,
+                           silicon=SiliconConfig(cap_sigma=0.0,
+                                                 comparator_sigma_v=0.0),
+                           **kw)
+    return ServeEngine(params, cfg, fleet=fleet, **kw)
+
+
+class TestProgramSpans:
+    def test_profile_holds_batcher_and_engine_spans(self, tmp_path):
+        from repro.traffic.batching import ContinuousBatcher, VirtualClock
+        from repro.traffic.workload import TrafficRequest
         cfg = _cfg()
-        eng = ServeEngine(_params(cfg), cfg, slots=2, max_len=32,
-                          batched_prefill=False, tracing=True,
-                          trace_tick_interval=1)
-        traces = 0
-        inner = make_serve_step(cfg, trace_tag=eng.trace_tag)
+        eng = _engine("pinned", cfg, _params(cfg), max_len=32,
+                      batched_prefill=True)
+        inf = float("inf")
+        reqs = [TrafficRequest(rid=i, t_arrival_s=0.0, prompt=[1, 2, 3],
+                               max_new_tokens=3, ttft_deadline_s=inf,
+                               deadline_s=inf) for i in range(3)]
+        log, spans = _profile(tmp_path, lambda: ContinuousBatcher(
+            eng, VirtualClock(0.01)).run(reqs))
+        assert log.ticks > 0
+        names = {s[0] for s in spans}
+        assert {"batcher.tick", "batcher.queue", "batcher.admit",
+                "batcher.clock", "batcher.observe", "serve.admit",
+                "serve.prefill", "serve.step", "serve.dispatch",
+                "serve.sync", "serve.bookkeep"} <= names
+        steps = [s for s in spans if s[0] == "serve.step"]
+        ticks = [s for s in spans if s[0] == "batcher.tick"]
+        for _, a, b, _ in (s for s in spans if s[0] == "serve.sync"):
+            assert any(x <= a and b <= y for _, x, y, _ in steps)
+        for _, a, b, _ in steps:
+            assert any(x <= a and b <= y for _, x, y, _ in ticks)
+        # The admitted request ids ride the admission span, all of them.
+        rids = [int(r) for s in spans if s[0] == "batcher.admit"
+                for r in str(s[3]["rids"]).split()]
+        assert sorted(rids) == [0, 1, 2]
 
-        def counting(params, cache, tokens, rng, step=0, active=0):
-            nonlocal traces
-            traces += 1
-            return inner(params, cache, tokens, rng, step, active)
+    def test_span_without_bus_appends_nothing(self):
+        assert obs.bus() is None
+        seq = obs_trace._SEQ              # bus-wide emission index
+        with obs_trace.span("serve.step", stream=1):
+            pass
+        assert obs_trace._SEQ == seq and obs.bus() is None
 
-        eng._traced_step_fn = jax.jit(counting)
-        with obs.tracing() as first:
-            _serve(eng, n=3)
-        with obs.tracing() as second:   # fresh bus: must NOT retrace
-            _serve(eng, n=3)
-        _serve(eng, n=3)                # no bus at all: still no retrace
-        assert traces == 1
-        assert len(first.by_kind("decode_tick")) > 0
-        assert len(second.by_kind("decode_tick")) > 0
 
-    def test_cadence_samples_every_interval_ticks(self):
-        cfg = _cfg()
-        eng = ServeEngine(_params(cfg), cfg, slots=1, max_len=32,
-                          batched_prefill=False, tracing=True,
-                          trace_tick_interval=4)
-        with obs.tracing() as buf:
-            eng.run([Request(prompt=[1], max_new_tokens=12)])
-        streams = [e.stream for e in buf.by_kind("decode_tick")]
-        assert streams == [0, 4, 8]
+class TestStepScopes:
+    """The compiled decode and prefill programs name their layers in the
+    op metadata a profile reads (``op_name``)."""
 
-    def test_decode_tick_payload(self):
-        cfg = _cfg()
-        eng = ServeEngine(_params(cfg), cfg, slots=2, max_len=32,
-                          batched_prefill=False, tracing=True,
-                          trace_tick_interval=1)
-        with obs.tracing() as buf:
-            _serve(eng, n=2)
-        ev = buf.by_kind("decode_tick")[0]
-        assert ev.engine == eng.trace_tag
-        assert ev.data["active"] == 2 and len(ev.data["tokens"]) == 2
-
-    def test_interval_validation(self):
-        cfg = _cfg()
-        with pytest.raises(ValueError, match="trace_tick_interval"):
-            ServeEngine(_params(cfg), cfg, slots=1, max_len=8,
-                        tracing=True, trace_tick_interval=0)
+    @pytest.mark.parametrize("route", ["lossless", "planes", "swapped",
+                                       "kernel", "kernel_silicon"])
+    def test_scopes_in_op_metadata(self, route):
+        import dataclasses
+        import re
+        cim = dataclasses.replace(CIM, use_kernel=route.startswith("kernel"))
+        cfg = _cfg(mf=MFTechniqueConfig(mode="cim_sim", cim=cim))
+        kind = {"planes": "silicon"}.get(route, route)
+        eng = _engine(kind, cfg, _params(cfg), batched_prefill=True)
+        assert not hasattr(eng, "_traced_step_fn")
+        step = eng.step_fn.lower(
+            eng._exec_params, eng.cache, jnp.zeros((2,), jnp.int32),
+            jax.random.PRNGKey(0), jnp.int32(0)).compile().as_text()
+        prefill = eng._prefill_fn.lower(
+            eng._exec_params, eng.cache, jnp.zeros((2, 4), jnp.int32),
+            jnp.zeros((2,), jnp.int32), jnp.int32(0)).compile().as_text()
+        for text, extra in ((step, ("lm_head", "sampling")), (prefill, ())):
+            paths = set(re.findall(r'op_name="([^"]*)"', text))
+            parts = {p for path in paths for p in path.split("/")}
+            assert {"attention", "kv_write", "mf_projection",
+                    *extra} <= parts
+            assert any(f"/mf_projection/{route}/" in p for p in paths)
+            assert "callback" not in text
 
 
 class TestDisabledPathParity:
-    """Tokens must be bitwise identical with tracing off (today's
-    program), with a bus installed against an untraced engine, and with
-    the traced twin dispatched every tick."""
+    """Tokens must be bitwise identical with no bus and no profiler, with
+    a bus installed, and with a ``jax.profiler`` trace running: spans
+    and scopes are host annotations and op metadata, never values."""
 
     @pytest.mark.parametrize("kind", ["pinned", "swapped", "silicon"])
-    def test_bitwise_parity(self, kind):
-        from repro.silicon.instance import SiliconConfig
+    def test_bitwise_parity(self, kind, tmp_path):
         cfg = _cfg()
         params = _params(cfg)
-        sigma0 = SiliconConfig(cap_sigma=0.0, comparator_sigma_v=0.0)
-
-        def build(tracing):
-            kw = dict(slots=2, max_len=16, batched_prefill=False,
-                      tracing=tracing, trace_tick_interval=1)
-            if kind == "pinned":
-                return ServeEngine(params, cfg,
-                                   fleet=Fleet(n_macros=1024, cfg=CIM),
-                                   **kw)
-            if kind == "swapped":
-                return ServeEngine(params, cfg,
-                                   fleet=Fleet(n_macros=8, cfg=CIM), **kw)
-            return ServeEngine(params, cfg,
-                               fleet=Fleet(n_macros=1024, cfg=CIM),
-                               silicon=sigma0, **kw)
-
-        probe = build(False)
+        probe = _engine(kind, cfg, params)
         if kind == "swapped":
             assert not probe.schedule.pinned
             assert probe.schedule.rounds_max > 1
         assert obs.bus() is None
         ref = _serve(probe)
         with obs.tracing() as buf:
-            assert _serve(build(False)) == ref    # host emitters only
-            assert _serve(build(True)) == ref     # in-jit emission
-            assert len(buf.by_kind("decode_tick")) > 0
+            assert _serve(_engine(kind, cfg, params)) == ref
+        assert len(buf.by_kind("admit")) > 0
+        traced, spans = _profile(
+            tmp_path, lambda: _serve(_engine(kind, cfg, params)))
+        assert traced == ref
+        assert any(s[0] == "serve.step" for s in spans)
+
+
+class TestPrefillSlabCounter:
+    def test_slab_tokens_are_slots_times_bucket(self):
+        cfg = _cfg()
+        eng = _engine("pinned", cfg, _params(cfg), slots=4, max_len=32,
+                      batched_prefill=True)
+        slab = eng.metrics["serve_prefill_slab_tokens_total"]
+        tokens = eng.metrics["serve_prefill_tokens_total"]
+        # Waves of 2 prompts (longest 6 tokens: 5 ingested, bucket 8) and
+        # of 1 prompt (3 tokens: 2 ingested, bucket 2).
+        eng.submit_many([Request(prompt=[1] * 6, max_new_tokens=2),
+                         Request(prompt=[2] * 3, max_new_tokens=2)])
+        assert (slab.value, tokens.value) == (4 * 8, 5 + 2)
+        eng.submit_many([Request(prompt=[3] * 3, max_new_tokens=2)])
+        assert (slab.value, tokens.value) == (4 * 8 + 4 * 2, 5 + 2 + 2)
+        assert eng.counters()["prefill_calls"] == 2
 
 
 class TestMetrics:
