@@ -11,6 +11,11 @@ Two execution regimes:
     combine exposed for the shard_map flash-decode path in
     `parallel/collectives.py`.
 
+GQA/MQA is contracted per KV-head group: query head ``h = kv * g + j``
+(``g = n_heads // n_kv_heads``) meets KV head ``kv`` in place, so the
+cache is never repeated per query head. MHA is the ``g == 1`` case of the
+same code.
+
 QKV/O projections run through `core.mf.apply_projection`, so attention
 projections participate in the MF mixed mapping like every other layer.
 """
@@ -59,15 +64,6 @@ def _split_heads(v: jax.Array, n: int) -> jax.Array:
     return v.reshape(b, t, n, -1)
 
 
-def _repeat_kv(v: jax.Array, groups: int) -> jax.Array:
-    """(B, T, Hkv, D) -> (B, T, Hkv*groups, D) for GQA."""
-    if groups == 1:
-        return v
-    b, t, h, d = v.shape
-    return jnp.broadcast_to(v[:, :, :, None, :], (b, t, h, groups, d)
-                            ).reshape(b, t, h * groups, d)
-
-
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True, window: Optional[int] = None,
                       q_offset: int = 0, block: int = 1024,
@@ -91,19 +87,17 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, tq, h, d = q.shape
     _, tk, hkv, _ = k.shape
     dv = v.shape[-1]
-    groups = h // hkv
-    k = _repeat_kv(k, groups)
-    v = _repeat_kv(v, groups)
+    g = h // hkv
     scale = 1.0 / math.sqrt(d)
-    qf = q.astype(jnp.float32) * scale
+    qf = (q.astype(jnp.float32) * scale).reshape(b, tq, hkv, g, d)
 
     nblocks = -(-tk // block)
     pad = nblocks * block - tk
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kb = k.reshape(b, nblocks, block, h, d)
-    vb = v.reshape(b, nblocks, block, h, dv)
+    kb = k.reshape(b, nblocks, block, hkv, d)
+    vb = v.reshape(b, nblocks, block, hkv, dv)
 
     q_pos = q_offset + jnp.arange(tq)
 
@@ -111,33 +105,33 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         m, l, o = carry
         kc, vc, blk_idx = inp
         k_pos = blk_idx * block + jnp.arange(block)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kc.astype(jnp.float32))
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qf, kc.astype(jnp.float32))
         mask = jnp.ones((tq, block), bool)
         if causal:
             mask &= q_pos[:, None] >= k_pos[None, :]
         if window is not None:
             mask &= q_pos[:, None] - k_pos[None, :] < window
         mask &= (k_pos < tk)[None, :]            # padding keys
-        s = jnp.where(mask[None, None], s, NEG_INF)
+        s = jnp.where(mask[None, None, None], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         # Fully-masked rows have s == m_new == NEG_INF -> exp(0) == 1;
         # zero them explicitly so they contribute nothing.
-        p = jnp.where(mask[None, None], p, 0.0)
+        p = jnp.where(mask[None, None, None], p, 0.0)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
         o_new = o * corr[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p, vc.astype(jnp.float32))
+            "bkgqs,bskd->bkgqd", p, vc.astype(jnp.float32))
         return (m_new, l_new, o_new), None
 
-    m0 = jnp.full((b, h, tq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, h, tq), jnp.float32)
-    o0 = jnp.zeros((b, h, tq, dv), jnp.float32)
+    m0 = jnp.full((b, hkv, g, tq), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((b, hkv, g, tq), jnp.float32)
+    o0 = jnp.zeros((b, hkv, g, tq, dv), jnp.float32)
     (m, l, o), _ = jax.lax.scan(
         body, (m0, l0, o0),
         (jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0),
          jnp.arange(nblocks)))
-    out = o / jnp.maximum(l, 1e-30)[..., None]
+    out = (o / jnp.maximum(l, 1e-30)[..., None]).reshape(b, h, tq, dv)
     return jnp.moveaxis(out, 1, 2).astype(q.dtype)    # (B, Tq, H, D)
 
 
@@ -149,18 +143,16 @@ def _blocked_attention_skip(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, tq, h, d = q.shape
     _, tk, hkv, _ = k.shape
     dv = v.shape[-1]
-    groups = h // hkv
-    k = _repeat_kv(k, groups)
-    v = _repeat_kv(v, groups)
+    g = h // hkv
     scale = 1.0 / math.sqrt(d)
 
     nq, nk = -(-tq // block), -(-tk // block)
     qp = jnp.pad(q, ((0, 0), (0, nq * block - tq), (0, 0), (0, 0)))
     kp = jnp.pad(k, ((0, 0), (0, nk * block - tk), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, nk * block - tk), (0, 0), (0, 0)))
-    qb = (qp.astype(jnp.float32) * scale).reshape(b, nq, block, h, d)
-    kb = kp.reshape(b, nk, block, h, d)
-    vb = vp.reshape(b, nk, block, h, dv)
+    qb = (qp.astype(jnp.float32) * scale).reshape(b, nq, block, hkv, g, d)
+    kb = kp.reshape(b, nk, block, hkv, d)
+    vb = vp.reshape(b, nk, block, hkv, dv)
 
     def kv_blocks_for(i: int) -> list[int]:
         q_lo, q_hi = i * block + q_offset, i * block + q_offset + block - 1
@@ -177,7 +169,7 @@ def _blocked_attention_skip(q: jax.Array, k: jax.Array, v: jax.Array, *,
     def partial_block(i: int, j: int, m, l, o):
         kj = kb[:, j]
         vj = vb[:, j]
-        s = jnp.einsum("bqhd,bkhd->bhqk", qb[:, i], kj.astype(jnp.float32))
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qb[:, i], kj.astype(jnp.float32))
         q_pos = q_offset + i * block + jnp.arange(block)
         k_pos = j * block + jnp.arange(block)
         mask = jnp.ones((block, block), bool)
@@ -186,29 +178,29 @@ def _blocked_attention_skip(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if window is not None:
             mask &= q_pos[:, None] - k_pos[None, :] < window
         mask &= (k_pos < tk)[None, :]
-        s = jnp.where(mask[None, None], s, NEG_INF)
+        s = jnp.where(mask[None, None, None], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
-        p = jnp.where(mask[None, None], p, 0.0)
+        p = jnp.where(mask[None, None, None], p, 0.0)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
         o_new = o * corr[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p, vj.astype(jnp.float32))
+            "bkgqs,bskd->bkgqd", p, vj.astype(jnp.float32))
         return m_new, l_new, o_new
 
     # Static Python double loop: per-q-block accumulators stay LOCAL
-    # (b,h,block,dv) values — no (nq, ...) gather/scatter buffers whose
+    # (b,hkv,g,block,dv) values — no (nq, ...) gather/scatter buffers whose
     # full-size dynamic-update-slices would dominate bytes accessed.
     outs = []
     for i in range(nq):
-        m = jnp.full((b, h, block), NEG_INF, jnp.float32)
-        l = jnp.zeros((b, h, block), jnp.float32)
-        o = jnp.zeros((b, h, block, dv), jnp.float32)
+        m = jnp.full((b, hkv, g, block), NEG_INF, jnp.float32)
+        l = jnp.zeros((b, hkv, g, block), jnp.float32)
+        o = jnp.zeros((b, hkv, g, block, dv), jnp.float32)
         for j in kv_blocks_for(i):
             m, l, o = partial_block(i, j, m, l, o)
         outs.append(o / jnp.maximum(l, 1e-30)[..., None])
-    out = jnp.concatenate(outs, axis=2)            # (b, h, nq*block, dv)
-    out = out[:, :, :tq]
+    out = jnp.concatenate(outs, axis=3)        # (b, hkv, g, nq*block, dv)
+    out = out[:, :, :, :tq].reshape(b, h, tq, dv)
     return jnp.moveaxis(out, 1, 2).astype(q.dtype)
 
 
@@ -224,19 +216,22 @@ def decode_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     b, s, hkv, d = k.shape
     h = q.shape[1]
-    groups = h // hkv
-    k = _repeat_kv(k, groups)
-    v = _repeat_kv(v, groups)
     scale = 1.0 / math.sqrt(d)
-    sco = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32) * scale,
-                     k.astype(jnp.float32))
-    sco = jnp.where(valid[:, None, :], sco, NEG_INF)
+    qg = (q.astype(jnp.float32) * scale).reshape(b, hkv, h // hkv, d)
+    # HIGHEST keeps the sums f32: at default precision a dot with g > 1
+    # query rows goes to the MXU as one bfloat16 pass.
+    hi = jax.lax.Precision.HIGHEST
+    sco = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32),
+                     precision=hi)
+    ok = valid[:, None, None, :]
+    sco = jnp.where(ok, sco, NEG_INF)
     m = jnp.max(sco, axis=-1)
     p = jnp.exp(sco - m[..., None])
-    p = jnp.where(valid[:, None, :], p, 0.0)
+    p = jnp.where(ok, p, 0.0)
     l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("bhs,bshd->bhd", p, v.astype(jnp.float32))
-    return m, l, o
+    o = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32),
+                   precision=hi)
+    return m.reshape(b, h), l.reshape(b, h), o.reshape(b, h, -1)
 
 
 def combine_partials(parts: list[tuple[jax.Array, jax.Array, jax.Array]]
