@@ -23,6 +23,9 @@ default precision) or ``bf16`` (the model computed in bfloat16: one pass,
 and every stored activation rounded to bfloat16: the control of a float32
 configuration at ``default``). The passes are emulated from bfloat16
 parts, so they read the same on any backend.
+
+``token_ops`` counts the model's operations for one output token, from
+its shapes (what ``decode_mfu`` reads).
 """
 
 from __future__ import annotations
@@ -234,7 +237,7 @@ def row_logits(params, tokens, picks, sizes_items, cim_items,
 def kv_rows(params, tokens, sizes_items, cim_items,
             precision: str = "highest"):
     """The first layer's k and v (B, T, kv, hd) of each row of ``tokens``
-    (B, T), as a KV cache holds them."""
+    (B, T), as a KV cache holds them, by leaf name."""
     s, cim = dict(sizes_items), dict(cim_items)
     first = jax.tree.map(lambda a: a[0], params["layers"][0])
 
@@ -243,4 +246,38 @@ def kv_rows(params, tokens, sizes_items, cim_items,
         _, k, v = _qkv(x, first, s, cim, precision)
         return k, v
 
-    return jax.lax.map(row, tokens)
+    k, v = jax.lax.map(row, tokens)
+    return {"k": k, "v": v}
+
+
+# ---------------------------------------------------------------------------
+# Operations, from shapes alone
+# ---------------------------------------------------------------------------
+# Counted from the configuration and the design point, never from a
+# kernel's grid or a compiled program, so a rewritten kernel or step is
+# read against the same work.
+
+def mf_projections(sizes: dict) -> list[tuple[int, int]]:
+    """(K, N) of every multiplication-free projection of one layer."""
+    d, hd = sizes["hidden_size"], sizes["head_dim"]
+    q = sizes["num_attention_heads"] * hd
+    kv = sizes["num_key_value_heads"] * hd
+    ff = sizes["intermediate_size"]
+    return [(d, q), (d, kv), (d, kv), (q, d), (d, ff), (d, ff), (ff, d)]
+
+
+def mf_ops(k: int, n: int) -> int:
+    """Operations of one row through one MF projection: two correlations
+    (sign(x)|w| and |x|sign(w)), each K*N multiply-adds of 2 operations.
+    The CIM planes are not counted: the lossless collapse skips them."""
+    return 4 * k * n
+
+
+def token_ops(sizes: dict, cache_len: int) -> int:
+    """Operations of one output token at ``cache_len`` positions attended:
+    every layer's MF projections and attention (scores and values, 2
+    operations per multiply-add each), then the float LM head."""
+    per_layer = sum(mf_ops(k, n) for k, n in mf_projections(sizes))
+    attn = 2 * 2 * sizes["num_attention_heads"] * sizes["head_dim"] * cache_len
+    head = 2 * sizes["hidden_size"] * sizes["vocab_size"]
+    return sizes["num_hidden_layers"] * (per_layer + attn) + head

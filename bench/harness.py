@@ -5,10 +5,17 @@ Everything specific to a configuration, a traffic mix or a metric is found
 by name under ``bench/``: ``configs/<config>.json`` (with the plain
 reference module it names beside it), ``traffic/<mix>.json`` and
 ``metrics/<metric>.py`` (one file may serve ``<metric>.<split>``).
+
+The reference module gives ``init_params(seed, sizes)``, ``row_logits``
+and ``kv_rows`` (see ``compare``), and may give ``token_ops(sizes,
+cache_len)`` (the model's operations for one output token, which
+``RunData.token_ops`` hands to the readers) and ``KV_ENTRY`` (the path of
+the cache entry ``kv_rows`` stands for; ``("layers", 0, "attn")``).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import gc
@@ -18,7 +25,7 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,10 +54,13 @@ CHECK_ROWS = 16
 # Logits every decode step keeps for the check, at this many vocabulary
 # ids drawn from the seed.
 LOGIT_IDS = 512
-# A cached k or v row disagrees with the reference where its relative
-# error is above this (float32 rounding reads about 1e-7; bfloat16 storage
-# about 1e-3).
+# A cached row of a leaf (k, v; a latent) disagrees with the reference
+# where its relative error is above this (float32 rounding reads about
+# 1e-7; bfloat16 storage about 1e-3).
 KV_TOL = 1e-4
+# The cache entry the check reads, where the reference names none: the
+# first layer's attention (period 0 of the layer scan).
+KV_ENTRY = ("layers", 0, "attn")
 
 
 def log(msg: str) -> None:
@@ -60,6 +70,17 @@ def log(msg: str) -> None:
 def load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def freeze(value):
+    """A configuration value as a static argument can take it: a dict
+    becomes the tuple of its sorted items, a list a tuple, all the way
+    down."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, freeze(v)) for k, v in value.items()))
+    if isinstance(value, list):
+        return tuple(freeze(v) for v in value)
+    return value
 
 
 def load_module(path: Path):
@@ -108,7 +129,10 @@ def reader(name: str, root: Path = BENCH):
 
 @dataclasses.dataclass
 class RunData:
-    """What a metric reader gets."""
+    """What a metric reader gets: the window's requests, its program's
+    counters (deltas over the window of ``engine.metrics``), the trace of
+    a traced run, and the configuration's ``token_ops`` (None where its
+    reference gives none)."""
 
     records: list
     window_s: float
@@ -116,6 +140,8 @@ class RunData:
     sizes: dict
     peaks: Optional[Peaks]
     trace: Optional[tr.Trace] = None
+    counters: dict = dataclasses.field(default_factory=dict)
+    token_ops: Optional[Callable] = None
 
 
 # ---------------------------------------------------------------------------
@@ -266,7 +292,8 @@ class Capture:
     """Wraps the engine's decode program (``engine.step_fn``): after each
     step it keeps, on the device, the logits the step produced at ``ids``
     for every slot, and on the host which request and which of its output
-    tokens each slot served. ``inner`` is the program it calls."""
+    tokens each slot served (so also how many steps fed each request).
+    ``inner`` is the program it calls."""
 
     def __init__(self, engine, ids: np.ndarray):
         self.engine, self.inner = engine, engine.step_fn
@@ -285,6 +312,11 @@ class Capture:
     def clear(self) -> None:
         self.rows, self.owners = [], []
 
+    def steps_of(self) -> collections.Counter:
+        """key -> decode steps that fed the request in its slot."""
+        return collections.Counter(own[0] for owners in self.owners
+                                   for own in owners if own is not None)
+
     def logits_of(self, keys: set) -> dict:
         """key -> (outputs, ids) logits the program produced for each
         request whose key is in ``keys``."""
@@ -298,25 +330,33 @@ class Capture:
                 for k, v in got.items()}
 
 
-def cached_rows(engine, records: dict) -> dict:
-    """The first layer's k and v that the engine's cache holds for every
-    request in flight, its own length of them, and the tokens they were
-    made from (the prompt and every served token but the last)."""
+def cached_rows(engine, records: dict, steps: dict,
+                entry: tuple = KV_ENTRY) -> dict:
+    """The leaves of the cache entry at ``entry`` (period 0) that the
+    engine holds for every request in flight (``rows``: leaf -> (slots,
+    positions, ...)), its own count of written positions (``len``), and
+    the tokens the cache was made from (``seqs``): the prompt tokens a
+    prefill wave ingested, then one token for each decode step that fed
+    the request (``steps``, by request key)."""
     slots, seqs = [], []
     for s, req in enumerate(engine.requests):
         if req is None:
             continue
         rec = records[id(req.prompt)]
-        n = len(rec.prompt) - 1 + len(req.out)
+        waved = len(rec.prompt) - 1 if engine.batched_prefill else 0
+        n = waved + steps.get(rec.key, 0)
         slots.append(s)
         seqs.append((list(rec.prompt) + list(req.out))[:n])
-    attn = engine.cache["layers"][0]["attn"]
+    node = engine.cache
+    for key in entry:
+        node = node[key]
     # Every slot's rows (in-flight ones first), a fixed shape.
     idx = jnp.asarray(slots + [s for s in range(engine.slots)
                                if s not in slots], jnp.int32)
-    k, v, n_prog = jax.device_get((attn["k"][0, idx], attn["v"][0, idx],
-                                   attn["len"][0, idx]))
-    return {"seqs": seqs, "k": k, "v": v, "len": n_prog}
+    rows, n_prog = jax.device_get((
+        {k: v[0, idx] for k, v in node.items() if k != "len"},
+        node["len"][0, idx]))
+    return {"seqs": seqs, "rows": rows, "len": n_prog}
 
 
 # ---------------------------------------------------------------------------
@@ -381,17 +421,20 @@ def compare(cell: Cell, sv, control: str = "") -> dict:
       seed's ids) from the reference's, the median (largest) over the
       sample;
     * ``kv_mismatch_share``: of the positions the cache holds for the
-      requests in flight at the close, the share whose first-layer k or v
-      lies off the reference's by more than ``KV_TOL``, or that the cache
-      does not count as written (``kv_rel_err_median``: the median
-      relative distance).
+      requests in flight at the close, the share at which any leaf of the
+      cache entry the reference's ``kv_rows`` stands for (a dict: leaf
+      name -> (B, T, ...) rows) lies off the reference's by more than
+      ``KV_TOL`` over all its axes after (slot, position), or that the
+      cache does not count as written (``kv_rel_err_median``: the median
+      over positions of the largest relative distance of any leaf).
 
-    With ``control`` (a lower precision of the reference): the same of
-    the reference at that precision put in the program's place, reading
-    the tokens it puts first and its own logits and cache rows."""
+    The reference gets the configuration's ``model`` and ``cim`` sections
+    as static arguments (``freeze``). With ``control`` (a lower precision
+    of the reference): the same of the reference at that precision put in
+    the program's place, reading the tokens it puts first and its own
+    logits and cache rows."""
     ref, conf = cell.reference, cell.conf
-    items = (tuple(sorted(conf["model"].items())),
-             tuple(sorted(conf["cim"].items())))
+    items = (freeze(conf["model"]), freeze(conf["cim"]))
     out: dict = {}
     if sv.sample:
         tokens, served, mask = reference_rows(sv.sample,
@@ -418,18 +461,21 @@ def compare(cell: Cell, sv, control: str = "") -> dict:
     if kv["seqs"]:
         # Fixed shapes (every slot, the whole cache), so the reference
         # compiles once per cell.
-        tokens = np.zeros(kv["k"].shape[:2], np.int32)
+        some = next(iter(kv["rows"].values()))
+        tokens = np.zeros(some.shape[:2], np.int32)
         for b, q in enumerate(kv["seqs"]):
             tokens[b, :len(q)] = q
-        k, v = jax.device_get(ref.kv_rows(sv.params, jnp.asarray(tokens),
+        want = jax.device_get(ref.kv_rows(sv.params, jnp.asarray(tokens),
                                           *items))
         if control:
-            gk, gv = jax.device_get(ref.kv_rows(
+            got = jax.device_get(ref.kv_rows(
                 sv.params, jnp.asarray(tokens), *items, control))
             n_prog = [len(q) for q in kv["seqs"]]
         else:
-            gk, gv, n_prog = kv["k"], kv["v"], kv["len"]
-        err = np.maximum(_rel(gk, k, (-2, -1)), _rel(gv, v, (-2, -1)))
+            got, n_prog = kv["rows"], kv["len"]
+        err = np.maximum.reduce([
+            _rel(got[leaf], w, tuple(range(2, w.ndim)))
+            for leaf, w in sorted(want.items())])
         errs, bad = [], 0
         for b, q in enumerate(kv["seqs"]):
             e = err[b, :len(q)]
@@ -477,8 +523,9 @@ def compile_counter():
 class Served:
     """One served window, with what the check and the readers need: the
     weights, the check's sample of finished requests with the logits the
-    program produced for them (``logits``, at ``ids``), and the cache rows
-    of the requests in flight at the close (``kv``)."""
+    program produced for them (``logits``, at ``ids``), the cache rows
+    of the requests in flight at the close (``kv``), and the program's
+    counters over the window (``counters``)."""
 
     params: dict
     records: list
@@ -492,6 +539,7 @@ class Served:
     ids: np.ndarray
     logits: Optional[np.ndarray]
     kv: dict
+    counters: dict
 
 
 def serve(cell: Cell, seed: int, seconds: float, traced: bool,
@@ -531,12 +579,16 @@ def serve(cell: Cell, seed: int, seconds: float, traced: bool,
             tracer = Tracer(tmp.name,
                             seconds - min(TRACE_SPAN_S, seconds / 3))
         setup_s = time.perf_counter() - t_process
+        before = engine.metrics.snapshot()
         with compile_counter() as compiles:
             records = serve_window(engine, specs, seconds, open_loop,
                                    tracer)
+        counters = engine.metrics.delta(before)
     mem = memory_peak(devices)
     log(f"bench: window {seconds}s, {compiles[0]} compilations inside it")
-    kv = cached_rows(engine, {r.key: r for r in records})
+    kv = cached_rows(engine, {r.key: r for r in records},
+                     capture.steps_of(),
+                     getattr(cell.reference, "KV_ENTRY", KV_ENTRY))
     sample = check_sample(records, seed)
     got = capture.logits_of({r.key for r in sample})
     logits = None
@@ -568,7 +620,7 @@ def serve(cell: Cell, seed: int, seconds: float, traced: bool,
     del engine, capture
     gc.collect()
     return Served(params, records, seconds, setup_s, mem, attempted, failed,
-                  trace, sample, ids, logits, kv)
+                  trace, sample, ids, logits, kv, counters)
 
 
 def judge(cell: Cell, numbers: dict, failed: int) -> dict:
@@ -599,7 +651,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     compared = judge(cell, numbers, sv.failed)
     correct = is_correct(compared)
     run = RunData(sv.records, seconds, sv.setup_s, cell.conf["model"],
-                  peaks, sv.trace)
+                  peaks, sv.trace, sv.counters,
+                  getattr(cell.reference, "token_ops", None))
     metrics = {}
     for m in cell.metrics:
         value = reader(m["name"])(run)
@@ -614,6 +667,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         device["window_s"] = sv.trace.window_s
         result["breakdown"] = {"device_ops": tr.top_ops(sv.trace),
                                "idle_gaps": tr.idle_gaps(sv.trace)}
+        for what, parts, scale in (
+                ("decode step ms by scope path",
+                 tr.scope_times(sv.trace, "jit_serve_step"), 1e3),
+                ("device idle s by innermost program span",
+                 tr.idle_by_span(sv.trace), 1.0)):
+            top = sorted(parts.items(), key=lambda kv: -kv[1])[:8]
+            log(f"bench: {what}: " + ", ".join(
+                f"{k or '(none)'} {v * scale:.6f}" for k, v in top))
     log(f"bench: checked {sum(len(r.tokens) for r in sv.sample)} served "
         f"tokens of {len(sv.sample)} finished requests and "
         f"{sum(len(q) for q in sv.kv['seqs'])} cached positions of "

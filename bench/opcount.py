@@ -1,39 +1,14 @@
-"""Operations and bytes of the served model's work, from shapes alone.
+"""Operations and bytes of a kernel's work, from shapes alone.
 
-Counted from the configuration and the design point, never from a
-kernel's grid or a compiled program, so a rewritten kernel or step is read
-against the same work.
+Counted from the design point, never from a kernel's grid or a compiled
+program, so a rewritten kernel is read against the same work. A
+configuration's operations per token are its reference's ``token_ops``
+(``configs/<reference>.py``).
 """
 
 from __future__ import annotations
 
 import math
-
-
-def mf_projections(sizes: dict) -> list[tuple[int, int]]:
-    """(K, N) of every multiplication-free projection of one layer."""
-    d, hd = sizes["hidden_size"], sizes["head_dim"]
-    q = sizes["num_attention_heads"] * hd
-    kv = sizes["num_key_value_heads"] * hd
-    ff = sizes["intermediate_size"]
-    return [(d, q), (d, kv), (d, kv), (q, d), (d, ff), (d, ff), (ff, d)]
-
-
-def mf_ops(k: int, n: int) -> int:
-    """Operations of one row through one MF projection: two correlations
-    (sign(x)|w| and |x|sign(w)), each K*N multiply-adds of 2 operations.
-    The CIM planes are not counted: the lossless collapse skips them."""
-    return 4 * k * n
-
-
-def token_ops(sizes: dict, cache_len: int) -> int:
-    """Operations of one output token at ``cache_len`` positions attended:
-    every layer's MF projections and attention (scores and values, 2
-    operations per multiply-add each), then the float LM head."""
-    per_layer = sum(mf_ops(k, n) for k, n in mf_projections(sizes))
-    attn = 2 * 2 * sizes["num_attention_heads"] * sizes["head_dim"] * cache_len
-    head = 2 * sizes["hidden_size"] * sizes["vocab_size"]
-    return sizes["num_hidden_layers"] * (per_layer + attn) + head
 
 
 def mav_sil_work(k: int, n: int, rows: int, w_bits: int, x_bits: int,

@@ -7,7 +7,9 @@ Everything the builder needs is data in the configuration file:
 * ``model``: the published sizes, under their published keys;
 * ``program.model``: the program's ``ModelConfig`` fields that the
   published keys do not give (``family``, ``qk_norm``, ``mlp_type``, ...);
-  any field named there wins over the one derived from ``model``;
+  any field named there wins over the one derived from ``model``. A dict
+  given for a field whose type is one of the program's dataclasses
+  (``moe``, ``mla``) is built into it, and a list becomes a tuple;
 * ``program.mf_mode``: how the projections run (``cim_sim``: on CIM
   macros);
 * ``cim``: the design point (``CimConfig`` fields) and the static
@@ -24,6 +26,7 @@ Everything the builder needs is data in the configuration file:
 from __future__ import annotations
 
 import dataclasses
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -48,11 +51,26 @@ def model_config(conf: dict):
           if k in PUBLISHED}
     if "rope_theta" in kw:
         kw["rope_theta"] = float(kw["rope_theta"])
-    kw.update(prog["model"])
+    hints = typing.get_type_hints(ModelConfig)
+    kw.update({k: field_value(hints[k], v)
+               for k, v in prog["model"].items()})
     return ModelConfig(name=conf["name"],
                        dtype=jnp.dtype(conf["activation_dtype"]),
                        mf=MFTechniqueConfig(mode=prog["mf_mode"], cim=cim),
                        **kw)
+
+
+def field_value(hint, value):
+    """``value`` as a field of type ``hint`` takes it: a dict for a
+    dataclass (or an optional one) becomes that dataclass, a list a
+    tuple."""
+    if isinstance(value, dict):
+        for t in (hint, *typing.get_args(hint)):
+            if dataclasses.is_dataclass(t):
+                return t(**value)
+    if isinstance(value, list):
+        return tuple(value)
+    return value
 
 
 def check_program_defaults(conf: dict) -> None:
